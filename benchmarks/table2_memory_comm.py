@@ -101,6 +101,7 @@ print("TABLE2" + json.dumps(out))
 def compiled_table() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"    # a fake-device rehearsal, never the chip
     res = subprocess.run([sys.executable, "-c", _COMPILED_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1200)
     for line in res.stdout.splitlines():

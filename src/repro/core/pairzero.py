@@ -24,7 +24,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.byzantine import behaviors as byz_behaviors
@@ -211,9 +210,10 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
                 s_seed = zo.perturb_seed(ctl["dsync_seed"], j)
                 lp_s, lm_s, _ = zo.dual_forward(
                     lambda p: loss_fn(p, batch), params, s_seed, mu,
-                    mode="fresh")
+                    mode="fresh", impl=impl)
             lp, lm, params_at = zo.dual_forward(
-                lambda p: loss_fn(p, batch), params, seed, mu, mode=mode)
+                lambda p: loss_fn(p, batch), params, seed, mu, mode=mode,
+                impl=impl)
             noise_key = jax.random.wrap_key_data(ctl["noise_bits"])
             round_key = jax.random.fold_in(noise_key, j)
             if client_axes:
@@ -270,7 +270,8 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
                     p_hat = transport.aggregate(p_k, ctl, round_key)
             # restore + update fused into one axpy (chained mode)
             params = zo.apply_update(params_at, seed, p_hat,
-                                     lr / n_perturb, mu, mode=mode)
+                                     lr / n_perturb, mu, mode=mode,
+                                     impl=impl)
             p_hat_sum += p_hat.astype(jnp.float32)
             loss_acc += jnp.mean(0.5 * (lp + lm)).astype(jnp.float32)
             if j == 0:
@@ -295,7 +296,6 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
     if not axes:
         raise ValueError(f"mesh {mesh.axis_names} has no client axes "
                          "(want 'pod' and/or 'data')")
-    auto = frozenset(a for a in mesh.axis_names if a not in axes)
     body = functools.partial(round_body, client_axes=axes)
 
     def sharded_step(params: PyTree, batch: Dict, ctl: Dict
@@ -321,10 +321,10 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
             with shd.manual_axes(axes):
                 return body(pr, ba, ct, client_ids=ci)
 
-        new_params, metrics = shard_map(
+        new_params, metrics = jax.shard_map(
             manual_body, mesh=mesh,
             in_specs=(repl(params), bspecs, repl(ctl), P(axes)),
-            out_specs=out_specs, check_rep=False, auto=auto)(
+            out_specs=out_specs, axis_names=set(axes), check_vma=False)(
                 params, batch, ctl, ids)
         # pin the carry back to the FSDP layout so a surrounding lax.scan
         # keeps one stable placement instead of round-tripping per round

@@ -24,7 +24,7 @@ floating-point drift, 2× memory) — tests use it as the oracle for `chained`.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -130,26 +130,28 @@ def draw_z(params: PyTree, seed) -> PyTree:
 # ---------------------------------------------------------------------------
 
 def dual_forward(loss_fn: Callable[[PyTree], jnp.ndarray], params: PyTree,
-                 seed, mu: float, mode: str = "chained"
+                 seed, mu: float, mode: str = "chained",
+                 impl: Optional[str] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, PyTree]:
     """Evaluate (loss(w+μz), loss(w−μz)) and return params positioned for update.
 
     Returns (loss_plus, loss_minus, params_at) where `params_at` is w−μz in
     chained mode (caller fuses restore+update via one axpy of (μ − η·p̂)·z)
-    or w itself in fresh mode (caller applies −η·p̂·z).
+    or w itself in fresh mode (caller applies −η·p̂·z). `impl` selects the
+    seeded_axpy kernel of the chained/fresh perturbations (None: auto).
     """
     if mode == "chained":
-        p_plus = perturb(params, seed, mu)           # w + μz   (donates w)
+        p_plus = perturb(params, seed, mu, impl)     # w + μz   (donates w)
         loss_plus = loss_fn(p_plus)
         # data-depend the second axpy on loss_plus so XLA cannot reorder the
         # buffer chain (the scalar add is free).
         anchor = (jnp.sum(loss_plus) * 0.0).astype(jnp.float32)
-        p_minus = perturb(p_plus, seed, -2.0 * mu + anchor)  # w − μz
+        p_minus = perturb(p_plus, seed, -2.0 * mu + anchor, impl)  # w − μz
         loss_minus = loss_fn(p_minus)
         return loss_plus, loss_minus, p_minus
     if mode == "fresh":
-        loss_plus = loss_fn(perturb(params, seed, mu))
-        loss_minus = loss_fn(perturb(params, seed, -mu))
+        loss_plus = loss_fn(perturb(params, seed, mu, impl))
+        loss_minus = loss_fn(perturb(params, seed, -mu, impl))
         return loss_plus, loss_minus, params
     if mode == "fused":
         # Perturbed weights never materialize tree-wide: consumers
@@ -174,16 +176,17 @@ def projection(loss_plus: jnp.ndarray, loss_minus: jnp.ndarray, mu: float,
 
 
 def apply_update(params_at: PyTree, seed, p_hat: jnp.ndarray,
-                 lr, mu: float, mode: str = "chained") -> PyTree:
+                 lr, mu: float, mode: str = "chained",
+                 impl: Optional[str] = None) -> PyTree:
     """Global model update w ← w − η p̂ z (Algorithm 1 line 14).
 
     chained: params_at = w−μz ⇒ one fused axpy of (μ − η p̂)·z restores and
     updates simultaneously. fresh: params_at = w ⇒ axpy of (−η p̂)·z.
     """
     if mode == "chained":
-        return perturb(params_at, seed, mu - lr * p_hat)
+        return perturb(params_at, seed, mu - lr * p_hat, impl)
     if mode in ("fresh", "fused"):
-        return perturb(params_at, seed, -lr * p_hat)
+        return perturb(params_at, seed, -lr * p_hat, impl)
     raise ValueError(f"unknown dual mode: {mode}")
 
 

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.seeded_axpy import gaussian_from_counter
 
 LANE = 128
@@ -55,9 +54,9 @@ def _pmm_kernel(seed_ref, off_ref, eps_ref, x_ref, w_ref, o_ref, acc_ref, *,
     c_iota = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1)
     k0 = (ki * bk).astype(jnp.uint32)
     n0 = (nj * bn).astype(jnp.uint32)
-    idx = off_ref[0] + (k0 + r_iota) * jnp.uint32(n_orig) + (n0 + c_iota)
-    z = gaussian_from_counter(idx, seed_ref[0])
-    wz = w_ref[...].astype(jnp.float32) + eps_ref[0] * z
+    idx = off_ref[0, 0] + (k0 + r_iota) * jnp.uint32(n_orig) + (n0 + c_iota)
+    z = gaussian_from_counter(idx, seed_ref[0, 0])
+    wz = w_ref[...].astype(jnp.float32) + eps_ref[0, 0] * z
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), wz,
                             preferred_element_type=jnp.float32)
 
@@ -115,10 +114,10 @@ def perturbed_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, npad), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray([seed]).astype(jnp.uint32),
-      jnp.asarray([off]).astype(jnp.uint32),
-      jnp.asarray([eps], jnp.float32), x, w)
+    )(jnp.asarray(seed).astype(jnp.uint32).reshape(1, 1),
+      jnp.asarray(off).astype(jnp.uint32).reshape(1, 1),
+      jnp.asarray(eps, jnp.float32).reshape(1, 1), x, w)
     return out[:m, :n]

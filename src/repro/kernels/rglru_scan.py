@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _rglru_kernel(a_ref, x_ref, h0_ref, o_ref, hlast_ref, state_ref, *,
@@ -80,7 +79,7 @@ def rglru_scan_pallas(a: jnp.ndarray, x: jnp.ndarray, h0: jnp.ndarray,
             jax.ShapeDtypeStruct((b, d), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((blk_d,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x, h0)

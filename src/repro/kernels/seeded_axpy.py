@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 LANE = 128
 DEFAULT_BLOCK = 2048 * LANE  # elements per grid step (1 MiB of f32 in VMEM)
@@ -49,8 +48,13 @@ def fmix32(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _bits_to_unit(bits: jnp.ndarray) -> jnp.ndarray:
-    """uint32 → float32 uniform in [2^-24, 1): top 24 bits as mantissa."""
-    f = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24)
+    """uint32 → float32 uniform in [2^-24, 1): top 24 bits as mantissa.
+
+    The shifted value is below 2^24, so going through int32 is exact on
+    every backend; Mosaic has no direct uint32 → float32 cast.
+    """
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    f = top.astype(jnp.float32) * jnp.float32(2 ** -24)
     return jnp.maximum(f, jnp.float32(2 ** -24))
 
 
@@ -110,7 +114,7 @@ def seeded_axpy_pallas(w: jnp.ndarray, seed: jnp.ndarray, scale,
         ],
         out_specs=pl.BlockSpec((rows_per_block, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(mat.shape, orig_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray([seed]).astype(jnp.uint32),

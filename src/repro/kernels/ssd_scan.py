@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
@@ -126,7 +125,7 @@ def ssd_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
             jax.ShapeDtypeStruct((B * H, 1, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, at, b, c, s0)

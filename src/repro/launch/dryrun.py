@@ -19,10 +19,12 @@ Usage:
     python -m repro.launch.dryrun --audit                 # capture variant
 Results append incrementally to --out (default results/dryrun.json).
 """
-# The VERY FIRST lines, before ANY other import (jax locks the device count
-# on first init):
+# The VERY FIRST lines, before ANY other import (jax locks the platform and
+# the device count on first init). This is a fake-device CPU rehearsal: it
+# must never take a TPU, also when another program starts it as a child.
 import os  # noqa: E402
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 

@@ -15,27 +15,19 @@ ordinary training/serving builds the mesh from the real device set.
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: axis_types (explicit-sharding API)
-    only exists on jax >= 0.5; 0.4.x defaults every axis to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU tests/examples (same axis names)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_client_mesh(spec: str = "auto", n_clients: int = 0):

@@ -77,8 +77,6 @@ def collective_bytes(hlo_text: str) -> Tuple[float, Dict[str, float]]:
 
 def compiled_cost(compiled) -> Tuple[float, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 

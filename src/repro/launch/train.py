@@ -34,18 +34,20 @@ from __future__ import annotations
 
 import argparse
 import json
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro import byzantine as byz
 from repro.configs.base import (ByzantineConfig, ChannelConfig, DesyncConfig,
-                                DPConfig, PairZeroConfig,
+                                DPConfig, ModelConfig, PairZeroConfig,
                                 PowerControlConfig, TransportConfig,
                                 ZOConfig)
 from repro.core import fedsim, transport
 from repro.data.pipeline import FederatedPipeline
 from repro.data.tasks import TaskSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.runtime.fault import ElasticSchedule, FaultModel
 from repro.runtime.inject import FaultInjector
@@ -250,8 +252,10 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main() -> None:
-    args = build_parser().parse_args()
+def make_configs(args: argparse.Namespace
+                 ) -> Tuple[ModelConfig, PairZeroConfig, FederatedPipeline]:
+    """(model config, pAirZero config, data pipeline) for parsed CLI args
+    — what `main` trains, for callers that rebuild the same run."""
     cfg = registry.get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -299,6 +303,17 @@ def main() -> None:
         n_clients=args.clients, per_client_batch=args.batch, seed=args.seed,
         frontend_tokens=cfg.frontend.n_frontend_tokens,
         d_model=cfg.d_model)
+    return cfg, pz, pipe
+
+
+def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
+    """Run the CLI; returns the printed summary plus per-round `losses`
+    and `p_hats`. `argv` defaults to `sys.argv[1:]`."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, pz, pipe = make_configs(args)
+    mechanism = pz.transport.mechanism
+    byzcfg, desynccfg = pz.byzantine, pz.desync
 
     fault = None
     if args.dropout_p or args.straggler_p:
@@ -445,9 +460,10 @@ def main() -> None:
     if audit_summary is not None:
         summary["audit"] = audit_summary
     print(json.dumps(summary, indent=2))
+    result = {**summary, "losses": res.losses, "p_hats": res.p_hats}
     if args.out:
         with open(args.out, "w") as f:
-            json.dump({**summary, "losses": res.losses}, f)
+            json.dump(result, f)
     if audit_summary is not None and not audit_summary.get("dominated", True):
         raise SystemExit("AUDIT FAILURE: empirical eps_hat "
                          f"{audit_summary['eps_hat']:.4f} exceeds the "
@@ -460,6 +476,7 @@ def main() -> None:
               f"{res.health_abort_round} — accountant charged only the "
               f"{res.steps} executed rounds", flush=True)
         raise SystemExit(3)
+    return result
 
 
 def run_audit(pz, res, attack_hook, args) -> dict:

@@ -44,6 +44,7 @@ _COLL_RE = re.compile(
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start)?\(")
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+_CUSTOM_CALL_RE = re.compile(r'custom_call_target="([^"]+)"')
 # literal groups {{0,1},{2,3}} / {} or iota form [groups,size]<=[n]
 _GROUPS_RE = re.compile(
     r"replica_groups=(\{(?:\{[^}]*\},?)*\}|\[[^\]]*\](?:<=\[[^\]]*\])?)")
@@ -106,6 +107,16 @@ def collective_census(hlo_text: str) -> Dict[str, Dict[str, Any]]:
     return census
 
 
+def custom_call_census(hlo_text: str) -> Dict[str, int]:
+    """Occurrences of each custom-call target in an HLO module. On a TPU,
+    every Pallas (Mosaic) kernel is a ``tpu_custom_call``; the XLA
+    fallbacks add none."""
+    census: Dict[str, int] = {}
+    for m in _CUSTOM_CALL_RE.finditer(hlo_text):
+        census[m.group(1)] = census.get(m.group(1), 0) + 1
+    return census
+
+
 @dataclass
 class CostStats:
     """XLA's own account of one compiled program (per-device numbers)."""
@@ -119,6 +130,7 @@ class CostStats:
     peak_bytes: int = 0             # arg + out + temp − alias
     generated_code_bytes: int = 0
     collectives: Dict[str, Dict[str, Any]] = field(default_factory=dict)
+    custom_calls: Dict[str, int] = field(default_factory=dict)
 
     @property
     def collective_bytes(self) -> float:
@@ -136,6 +148,7 @@ class CostStats:
             "generated_code_bytes": self.generated_code_bytes,
             "collective_bytes": self.collective_bytes,
             "collectives": self.collectives,
+            "custom_calls": self.custom_calls,
         }
 
 
@@ -166,8 +179,6 @@ def analyze_compiled(compiled) -> CostStats:
     """Read cost/memory/collective analyses off an already-compiled
     executable (`jit(f).lower(...).compile()`); never executes it."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):    # jax <= 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     stats = CostStats(
         flops=float(ca.get("flops", 0.0)),
         bytes_accessed=float(ca.get("bytes accessed", 0.0)))
@@ -191,6 +202,7 @@ def analyze_compiled(compiled) -> CostStats:
     except Exception:                    # text unavailable on some backends
         hlo = ""
     stats.collectives = collective_census(hlo)
+    stats.custom_calls = custom_call_census(hlo)
     return stats
 
 

@@ -25,10 +25,11 @@ import repro.launch.mesh as mesh_mod
 
 # shrink the production mesh to the 8 fake devices: (data=2, model=4)
 def small_mesh(*, multi_pod=False):
+    auto = jax.sharding.AxisType.Auto
     if multi_pod:
-        return mesh_mod.make_mesh_compat((2, 2, 2),
-                                         ("pod", "data", "model"))
-    return mesh_mod.make_mesh_compat((2, 4), ("data", "model"))
+        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(auto,) * 3)
+    return jax.make_mesh((2, 4), ("data", "model"), axis_types=(auto,) * 2)
 
 dr.make_production_mesh = small_mesh
 

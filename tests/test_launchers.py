@@ -66,3 +66,19 @@ def test_serve_cli_smoke():
         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-2000:]
     assert "tok/s" in res.stdout
+
+
+def test_train_main_takes_argv_and_returns_losses(monkeypatch, tmp_path):
+    """`train.main(argv)` runs in-process and returns the summary with the
+    per-round losses and p̂ (what chip_smoke.py reads)."""
+    from repro.launch import train
+    # a set cache variable makes the compile-cache helper a no-op here
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    out = train.main(["--arch", "opt-125m", "--reduced", "--rounds", "2",
+                      "--clients", "2", "--batch", "2", "--seq-len", "8",
+                      "--n-perturb", "1", "--eval-every", "0",
+                      "--engine", "scan", "--chunk-rounds", "2"])
+    assert out["rounds"] == 2
+    assert len(out["losses"]) == len(out["p_hats"]) == 2
+    assert out["final_loss"] == out["losses"][-1]
+    assert all(np.isfinite(out["losses"]))

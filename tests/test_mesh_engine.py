@@ -127,8 +127,8 @@ def test_mesh_with_model_axis_runs(tiny_model, make_pz, make_pipeline):
     the shard_map (TP). TP re-tiles contractions, so this is fp-tolerance
     equivalence, not bitwise — the lane proves the partial-auto path
     compiles and trains."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     pz = make_pz(scheme="solution", rounds=4, n_clients=8)
     ref, res = _runs(tiny_model, pz, make_pipeline, mesh, rounds=4, chunk=2)
     np.testing.assert_allclose(res.losses, ref.losses, rtol=2e-5, atol=2e-5)
@@ -256,9 +256,9 @@ def test_mesh_telemetry_is_numerically_passive(tiny_model, make_pz,
 def test_mesh_hlo_contains_client_all_reduce(tiny_model, make_pz,
                                              make_pipeline, mesh8):
     """Structured collective census of the mesh step's compiled HLO
-    (repro.obs.hlo): exactly two all-reduces — the OTA scalar aggregate
-    (Transport.aggregate_mesh's psum) and the loss mean — both spanning
-    the full 8-client axis; the single-device step compiles collective-
+    (repro.obs.hlo): one all-reduce carrying the OTA scalar aggregate
+    (Transport.aggregate_mesh's psum) and the loss gather, spanning the
+    full 8-client axis; the single-device step compiles collective-
     free; and the census byte total agrees with roofline's independent
     HLO parser on the same text."""
     pz = make_pz(scheme="solution", rounds=4, n_clients=8)
@@ -287,9 +287,11 @@ def test_mesh_hlo_contains_client_all_reduce(tiny_model, make_pz,
     meshed = jax.jit(mstep).lower(*args).compile().as_text()
     census = collective_census(meshed)
     ar = census["all-reduce"]
-    assert ar["count"] == 2             # OTA scalar aggregate + loss mean
-    assert ar["group_sizes"] == [8, 8]  # each spans the full client axis
-    assert ar["bytes"] > 0
+    # XLA's combiner fuses the two psums (the OTA payload [8] and the
+    # (L+, L-) gather [2, 8]) into ONE tuple all-reduce over all 8 clients
+    assert ar["count"] == 1
+    assert ar["group_sizes"] == [8]
+    assert ar["bytes"] == 4 * (8 + 2 * 8)
     # two independent HLO parsers, one answer: the census byte totals
     # must match roofline's analytic collective model on the same text
     total, by_op = collective_bytes(meshed)

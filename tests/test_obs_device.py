@@ -86,6 +86,20 @@ def test_census_ignores_non_collective_text():
     assert ohlo.collective_census(hlo) == {}
 
 
+def test_custom_call_census_counts_targets():
+    """Pallas kernels compile to `tpu_custom_call`s on a TPU; the census
+    counts each custom-call target of a module's HLO text."""
+    hlo = """
+  %a = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %p), custom_call_target="tpu_custom_call", backend_config="x"
+  %b = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %a), custom_call_target="tpu_custom_call"
+  %c = s32[4]{0} custom-call(s32[4]{0} %i), custom_call_target="AssumeGatherIndicesInBound"
+  %d = f32[8,128]{1,0} add(f32[8,128]{1,0} %a, f32[8,128]{1,0} %b)
+"""
+    assert ohlo.custom_call_census(hlo) == {
+        "tpu_custom_call": 2, "AssumeGatherIndicesInBound": 1}
+    assert ohlo.custom_call_census("") == {}
+
+
 # ---------------------------------------------------------------------------
 # 2. Compiled-executable introspection
 # ---------------------------------------------------------------------------
@@ -101,9 +115,11 @@ def test_analyze_compiled_reports_real_numbers():
     assert stats.peak_bytes > 0
     assert stats.argument_bytes >= 2 * 64 * 64 * 4
     assert stats.collectives == {}
+    assert stats.custom_calls == {}     # XLA:CPU, no Pallas kernel
     d = stats.to_dict()
     assert d["flops"] == stats.flops
     assert "collective_bytes" in d
+    assert d["custom_calls"] == {}
     text = ohlo.describe(stats)
     assert "flops" in text and "peak" in text
 

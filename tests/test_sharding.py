@@ -13,8 +13,8 @@ from repro.runtime import sharding as shd
 def mesh():
     # 1-device "production-shaped" mesh: axis names real, sizes 1 — lets the
     # spec logic run on CPU without fake-device flags
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _spec_for(mesh, tree, leaf_path):

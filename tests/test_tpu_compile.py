@@ -1,0 +1,99 @@
+"""The main-path Pallas kernels compile for a TPU v5e at OPT-125M widths.
+
+Nothing runs: each case lowers and compiles a kernel for one chip of a
+described (not attached) `v5e:2x2` topology, which is what the TPU
+compiler refuses or accepts, and checks that the compiled program holds
+the Mosaic kernel (`tpu_custom_call`). Interpret mode cannot catch a cast
+or a block shape Mosaic has no lowering for; these do.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the worker that runs
+this file keeps it until it exits. Keep every such compile in this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.perturbed_matmul import perturbed_matmul_pallas
+from repro.kernels.seeded_axpy import seeded_axpy_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a described chip's programs can be written to the persistent
+        # cache but never read back: keep the cache out of these compiles
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            cc.reset_cache()
+
+
+def _compiled_text(fn, *specs) -> str:
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("shape", [(12, 768, 3072), (50272, 768), (768,)],
+                         ids=["stacked_ffn", "embedding", "bias"])
+def test_seeded_axpy_compiles_for_v5e(one_chip, shape):
+    text = _compiled_text(
+        lambda w, seed, scale: seeded_axpy_pallas(w, seed, scale),
+        _spec(one_chip, shape), _spec(one_chip, (), jnp.uint32),
+        _spec(one_chip, ()))
+    assert "tpu_custom_call" in text
+
+
+def _pmm_specs(one_chip, m, k, n):
+    return (_spec(one_chip, (m, k)), _spec(one_chip, (k, n)),
+            _spec(one_chip, (), jnp.uint32), _spec(one_chip, (), jnp.uint32))
+
+
+@pytest.mark.parametrize("mkn", [(2560, 768, 3072), (2560, 3072, 768)],
+                         ids=["up_proj", "down_proj"])
+def test_perturbed_matmul_compiles_for_v5e(one_chip, mkn):
+    text = _compiled_text(
+        lambda x, w, seed, off, eps: perturbed_matmul_pallas(
+            x, w, seed, off, eps),
+        *_pmm_specs(one_chip, *mkn), _spec(one_chip, ()))
+    assert "tpu_custom_call" in text
+
+
+def test_perturbed_matmul_vmapped_over_eps_compiles_for_v5e(one_chip):
+    """The fused dual forward batches both rollouts (eps = ±μ) under one
+    vmap; the batched SMEM scalars must still satisfy Mosaic's tiling."""
+    m, k, n = 2560, 768, 3072
+
+    def both_rollouts(x, w, seed, off, eps):
+        return jax.vmap(lambda xe, e: perturbed_matmul_pallas(
+            xe, w, seed, off, e))(x, eps)
+
+    x, w, seed, off = _pmm_specs(one_chip, m, k, n)
+    text = _compiled_text(both_rollouts, _spec(one_chip, (2, m, k)), w,
+                          seed, off, _spec(one_chip, (2,)))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    qkv = _spec(one_chip, (8, 12, 64, 128))
+    text = _compiled_text(
+        lambda q, k, v: flash_attention_pallas(q, k, v, causal=True),
+        qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
