@@ -151,7 +151,6 @@ class AsyncCheckpointer:
                         keep=self.keep)
             if torn == "torn_write":
                 tear_checkpoint(path)
-                self._tracer.instant("ckpt_torn", step=step)
 
         try:
             inj.with_retries(attempt, site="ckpt_write",
@@ -178,32 +177,33 @@ class AsyncCheckpointer:
         import threading
         import time
 
-        t0 = time.perf_counter()
-        self.wait()
-        try:
-            if self._injector is not None:
-                self._injector.fire("ckpt_snapshot")
-            if self.double_buffer and any(
-                    isinstance(leaf, jax.Array)
-                    for leaf in jax.tree_util.tree_leaves(params)):
-                snap = _device_snapshot(params)
-                for leaf in jax.tree_util.tree_leaves(snap):
-                    leaf.copy_to_host_async()
-                self._thread = threading.Thread(
-                    target=self._write, args=(step, snap, extra),
-                    daemon=True)
-            else:
-                host_params = jax.tree_util.tree_map(
-                    lambda a: np.asarray(a), params)     # sync D2H baseline
-                self._thread = threading.Thread(
-                    target=self._write_host,
-                    args=(step, host_params, extra), daemon=True)
-            self._thread.start()
-        except Exception as exc:  # noqa: BLE001 - skip boundary, don't abort
-            self.snapshot_failures += 1
-            self._tracer.instant("ckpt_skipped", step=step,
-                                 error=type(exc).__name__)
-        t1 = time.perf_counter()
+        with self._tracer.annotation("ckpt_snapshot", step=step):
+            t0 = time.perf_counter()
+            self.wait()
+            try:
+                if self._injector is not None:
+                    self._injector.fire("ckpt_snapshot")
+                if self.double_buffer and any(
+                        isinstance(leaf, jax.Array)
+                        for leaf in jax.tree_util.tree_leaves(params)):
+                    snap = _device_snapshot(params)
+                    for leaf in jax.tree_util.tree_leaves(snap):
+                        leaf.copy_to_host_async()
+                    self._thread = threading.Thread(
+                        target=self._write, args=(step, snap, extra),
+                        daemon=True)
+                else:
+                    host_params = jax.tree_util.tree_map(   # sync D2H
+                        lambda a: np.asarray(a), params)       # baseline
+                    self._thread = threading.Thread(
+                        target=self._write_host,
+                        args=(step, host_params, extra), daemon=True)
+                self._thread.start()
+            except Exception as exc:  # noqa: BLE001 - skip the boundary
+                self.snapshot_failures += 1
+                self._tracer.instant("ckpt_skipped", step=step,
+                                     error=type(exc).__name__)
+            t1 = time.perf_counter()
         self.stall_s += t1 - t0
         # span == the exact stall_s increment (same endpoints): the
         # training-thread cost of dispatching this snapshot

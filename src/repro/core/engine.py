@@ -96,7 +96,8 @@ def build_trace(schedule, pz, t0: int, t1: int, *,
                 transport=None, fault=None, elastic=None,
                 channel=None, ctl_sharding=None,
                 behavior=None, defense=None,
-                desync=None) -> ControlTrace:
+                desync=None, tracer: ob.Tracer = ob.NULL_TRACER
+                ) -> ControlTrace:
     """Precompute the control trace for rounds [t0, t1).
 
     Mask generation consumes the (stateful) FaultModel RNG in round order, so
@@ -130,6 +131,11 @@ def build_trace(schedule, pz, t0: int, t1: int, *,
     per-round draws are seeded by (desync seed, round), so the trace is
     invariant to chunk boundaries and resume points. None keeps the rows
     absent — the historical block, bit for bit.
+
+    `tracer` times the two places the build leaves the host: the wait for
+    the eager round-seed and noise-key ops (`prep_device_wait`; they queue
+    behind whatever the device is running) and the control block's
+    transfer (`h2d_put`).
     """
     if transport is None:
         transport = tp.resolve(pz)
@@ -168,6 +174,8 @@ def build_trace(schedule, pz, t0: int, t1: int, *,
     c_slice = np.asarray(schedule.c[t0:t1], dtype=np.float64)
     sigma_slice = np.asarray(schedule.sigma[t0:t1], dtype=np.float64)
     masks = np.asarray(masks, dtype=np.float32)
+    with tracer.span("prep_device_wait", t0=t0, t1=t1):
+        jax.block_until_ready((seeds, noise_bits))
     host_ctl = {
         "seed": np.asarray(seeds, dtype=np.uint32),
         "c": np.asarray(c_slice, dtype=np.float32),
@@ -187,7 +195,8 @@ def build_trace(schedule, pz, t0: int, t1: int, *,
         host_ctl.update(dsync_rows)
     # one transfer for the whole block (sharded placement, when requested,
     # happens here rather than as a post-hoc reshard)
-    ctl = jax.device_put(host_ctl, ctl_sharding)
+    with tracer.span("h2d_put", t0=t0, t1=t1):
+        ctl = jax.device_put(host_ctl, ctl_sharding)
 
     if defense is not None:
         charged = bool(defense.charges_privacy(transport, schedule, pz))
@@ -274,9 +283,12 @@ class BatchStager:
         slot = self._slots[self._next]
         self._next = (self._next + 1) % len(self._slots)
         if slot["inflight"] is not None:
-            jax.block_until_ready(slot["inflight"])  # host buffer reusable
+            with self._tracer.span("prep_device_wait", t0=t0, t1=t1):
+                jax.block_until_ready(slot["inflight"])  # buffer reusable
             slot["inflight"] = None
-        per_round = [self._pipeline.batch(int(t)) for t in range(t0, t1)]
+        with self._tracer.span("batch_gen", t0=t0, t1=t1):
+            per_round = [self._pipeline.batch(int(t))
+                         for t in range(t0, t1)]
         rounds = len(per_round)
         host: Dict[str, np.ndarray] = {}
         for k, first in per_round[0].items():
@@ -291,7 +303,8 @@ class BatchStager:
                 buf[r] = b[k]
             host[k] = buf
         sharding = self._sharding_fn(host) if self._sharding_fn else None
-        out = jax.device_put(host, sharding)
+        with self._tracer.span("h2d_put", t0=t0, t1=t1):
+            out = jax.device_put(host, sharding)
         slot["inflight"] = out
         return out
 
@@ -326,8 +339,9 @@ class ChunkPrefetcher:
 
     Telemetry: each prep runs inside a `chunk_prep` span (on the worker
     thread when kicked), every kick drops a `prefetch_kick` instant, and
-    each `get` records a `prep_stall` span from the SAME perf_counter
-    endpoints that feed `stall_s` — span sums equal the scalar exactly.
+    each `get` is a live `prep_stall` span whose recorded endpoints are
+    the SAME perf_counter reads that feed `stall_s` — span sums equal the
+    scalar exactly.
 
     Degradation: a kicked prep that died on the worker thread no longer
     aborts the run from `get()` — the failure is logged as a
@@ -377,20 +391,21 @@ class ChunkPrefetcher:
         """Prepared payload for chunk i (blocks; stall time recorded)."""
         assert i == self._next, "chunks must be consumed in order"
         self._next += 1
-        t0 = time.perf_counter()
-        if self._fut is not None:
-            assert self._fut_i == i
-            try:
-                out = self._fut.result()
-            except Exception as exc:  # noqa: BLE001 - degrade, don't abort
-                self.degraded += 1
-                with self._tracer.span("prefetch_degraded", chunk=i,
-                                       error=type(exc).__name__):
-                    out = self._run_prepare(i, False)  # inline re-run, once
-            self._fut = None
-        else:
-            out = self._run_prepare(i, False)
-        t1 = time.perf_counter()
+        with self._tracer.annotation("prep_stall", chunk=i):
+            t0 = time.perf_counter()
+            if self._fut is not None:
+                assert self._fut_i == i
+                try:
+                    out = self._fut.result()
+                except Exception as exc:  # noqa: BLE001 - degrade
+                    self.degraded += 1
+                    with self._tracer.span("prefetch_degraded", chunk=i,
+                                           error=type(exc).__name__):
+                        out = self._run_prepare(i, False)  # re-run, once
+                self._fut = None
+            else:
+                out = self._run_prepare(i, False)
+            t1 = time.perf_counter()
         self.stall_s += t1 - t0
         self._tracer.add_span("prep_stall", t0, t1, chunk=i)
         return out

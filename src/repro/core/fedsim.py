@@ -461,7 +461,7 @@ class Experiment:
                                         ctl_sharding=ctl_shard,
                                         behavior=self.behavior,
                                         defense=self.defense,
-                                        desync=self.desync)
+                                        desync=self.desync, tracer=tr)
             return trace, stager.stage(a, b)
 
         prefetch = eng.ChunkPrefetcher(prepare, bounds,
@@ -505,7 +505,7 @@ class Experiment:
 
         try:
             for i, (a, b) in enumerate(bounds):
-                with tr.span("chunk", chunk=i, t0=a, t1=b):
+                with tr.step_span("chunk", i, chunk=i, t0=a, t1=b):
                     trace, batches = prefetch.get(i)
                     n_ok = eng.affordable_rounds(self.accountant, trace)
                     if n_ok == 0:

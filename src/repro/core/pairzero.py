@@ -214,66 +214,70 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
             lp, lm, params_at = zo.dual_forward(
                 lambda p: loss_fn(p, batch), params, seed, mu, mode=mode,
                 impl=impl)
-            noise_key = jax.random.wrap_key_data(ctl["noise_bits"])
-            round_key = jax.random.fold_in(noise_key, j)
-            if client_axes:
-                offset = client_ids[0]        # shard's first global client
-                p_local = zo.projection(lp, lm, mu, gamma)    # [K/n]
-                if desync is not None:
-                    p_local = ds.stale_payload(
-                        p_local, zo.projection(lp_s, lm_s, mu, gamma),
-                        ctl, offset)
-                if behavior is not None:
-                    p_local = byz_behaviors.apply_behavior(
-                        behavior, p_local, ctl, round_key, offset)
-                if defense is not None:
-                    p_local = defense.transmit(p_local, ctl)
-                    p_hat = defense.aggregate_mesh(
-                        transport, p_local, ctl, round_key, client_axes,
-                        offset)
+            # projection, the Transport's decode and its DP noise, and
+            # below the round's loss and p̂ metrics: `ota_aggregate`
+            with jax.named_scope("ota_aggregate"):
+                noise_key = jax.random.wrap_key_data(ctl["noise_bits"])
+                round_key = jax.random.fold_in(noise_key, j)
+                if client_axes:
+                    offset = client_ids[0]        # shard's first global client
+                    p_local = zo.projection(lp, lm, mu, gamma)    # [K/n]
+                    if desync is not None:
+                        p_local = ds.stale_payload(
+                            p_local, zo.projection(lp_s, lm_s, mu, gamma),
+                            ctl, offset)
+                    if behavior is not None:
+                        p_local = byz_behaviors.apply_behavior(
+                            behavior, p_local, ctl, round_key, offset)
+                    if defense is not None:
+                        p_local = defense.transmit(p_local, ctl)
+                        p_hat = defense.aggregate_mesh(
+                            transport, p_local, ctl, round_key, client_axes,
+                            offset)
+                    else:
+                        p_hat = transport.aggregate_mesh(
+                            p_local, ctl, round_key, client_axes, offset)
+                    if desync is not None:
+                        lp, lm, lp_s, lm_s = tp.client_all_gather(
+                            jnp.stack([lp, lm, lp_s, lm_s]), client_axes,
+                            offset, k_total)
+                    else:
+                        lp, lm = tp.client_all_gather(
+                            jnp.stack([lp, lm]), client_axes, offset, k_total)
+                    p_k = zo.projection(lp, lm, mu, gamma)        # [K], full
+                    # the full radiated payload for metrics/observations:
+                    # re-applying attack + PHY clip on the gathered vector is
+                    # bit-identical to the concatenation of the shard-local
+                    # payloads (elementwise ops; shared draws sliced per shard)
+                    if desync is not None:
+                        p_k = ds.stale_payload(
+                            p_k, zo.projection(lp_s, lm_s, mu, gamma), ctl)
+                    if behavior is not None:
+                        p_k = byz_behaviors.apply_behavior(
+                            behavior, p_k, ctl, round_key)
+                    if defense is not None:
+                        p_k = defense.transmit(p_k, ctl)
                 else:
-                    p_hat = transport.aggregate_mesh(
-                        p_local, ctl, round_key, client_axes, offset)
-                if desync is not None:
-                    lp, lm, lp_s, lm_s = tp.client_all_gather(
-                        jnp.stack([lp, lm, lp_s, lm_s]), client_axes,
-                        offset, k_total)
-                else:
-                    lp, lm = tp.client_all_gather(
-                        jnp.stack([lp, lm]), client_axes, offset, k_total)
-                p_k = zo.projection(lp, lm, mu, gamma)        # [K], full
-                # the full radiated payload for metrics/observations:
-                # re-applying attack + PHY clip on the gathered vector is
-                # bit-identical to the concatenation of the shard-local
-                # payloads (elementwise ops; shared draws sliced per shard)
-                if desync is not None:
-                    p_k = ds.stale_payload(
-                        p_k, zo.projection(lp_s, lm_s, mu, gamma), ctl)
-                if behavior is not None:
-                    p_k = byz_behaviors.apply_behavior(
-                        behavior, p_k, ctl, round_key)
-                if defense is not None:
-                    p_k = defense.transmit(p_k, ctl)
-            else:
-                p_k = zo.projection(lp, lm, mu, gamma)        # [K]
-                if desync is not None:
-                    p_k = ds.stale_payload(
-                        p_k, zo.projection(lp_s, lm_s, mu, gamma), ctl)
-                if behavior is not None:
-                    p_k = byz_behaviors.apply_behavior(
-                        behavior, p_k, ctl, round_key)
-                if defense is not None:
-                    p_k = defense.transmit(p_k, ctl)
-                    p_hat = defense.aggregate(transport, p_k, ctl,
-                                              round_key)
-                else:
-                    p_hat = transport.aggregate(p_k, ctl, round_key)
+                    p_k = zo.projection(lp, lm, mu, gamma)        # [K]
+                    if desync is not None:
+                        p_k = ds.stale_payload(
+                            p_k, zo.projection(lp_s, lm_s, mu, gamma), ctl)
+                    if behavior is not None:
+                        p_k = byz_behaviors.apply_behavior(
+                            behavior, p_k, ctl, round_key)
+                    if defense is not None:
+                        p_k = defense.transmit(p_k, ctl)
+                        p_hat = defense.aggregate(transport, p_k, ctl,
+                                                  round_key)
+                    else:
+                        p_hat = transport.aggregate(p_k, ctl, round_key)
             # restore + update fused into one axpy (chained mode)
             params = zo.apply_update(params_at, seed, p_hat,
                                      lr / n_perturb, mu, mode=mode,
                                      impl=impl)
-            p_hat_sum += p_hat.astype(jnp.float32)
-            loss_acc += jnp.mean(0.5 * (lp + lm)).astype(jnp.float32)
+            with jax.named_scope("ota_aggregate"):
+                p_hat_sum += p_hat.astype(jnp.float32)
+                loss_acc += jnp.mean(0.5 * (lp + lm)).astype(jnp.float32)
             if j == 0:
                 metrics["p_clients"] = p_k
                 if adversary is not None:
@@ -283,9 +287,10 @@ def make_zo_step(model_cfg: ModelConfig, pz: PairZeroConfig,
                     # is bit-identical to the signal the server inverted
                     metrics.update(
                         adversary.observe(transport, p_k, ctl, round_key))
-        metrics["loss"] = loss_acc / n_perturb
-        metrics["p_hat"] = p_hat_sum / n_perturb
-        metrics["k_eff"] = jnp.sum(ctl["mask"])
+        with jax.named_scope("ota_aggregate"):
+            metrics["loss"] = loss_acc / n_perturb
+            metrics["p_hat"] = p_hat_sum / n_perturb
+            metrics["k_eff"] = jnp.sum(ctl["mask"])
         return params, metrics
 
     if mesh is None:

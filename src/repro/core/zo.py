@@ -139,19 +139,31 @@ def dual_forward(loss_fn: Callable[[PyTree], jnp.ndarray], params: PyTree,
     chained mode (caller fuses restore+update via one axpy of (μ − η·p̂)·z)
     or w itself in fresh mode (caller applies −η·p̂·z). `impl` selects the
     seeded_axpy kernel of the chained/fresh perturbations (None: auto).
+
+    The θ passes run under the named scopes `theta_plus` / `theta_minus`
+    (`apply_update`'s under `theta_update`), so every op of a pass — the
+    kernel and each leaf's pads, reshapes and slices — carries its phase
+    in the compiled program's metadata (`repro.obs.hlo.op_scopes`).
     """
     if mode == "chained":
-        p_plus = perturb(params, seed, mu, impl)     # w + μz   (donates w)
+        with jax.named_scope("theta_plus"):
+            p_plus = perturb(params, seed, mu, impl)   # w + μz (donates w)
         loss_plus = loss_fn(p_plus)
-        # data-depend the second axpy on loss_plus so XLA cannot reorder the
-        # buffer chain (the scalar add is free).
-        anchor = (jnp.sum(loss_plus) * 0.0).astype(jnp.float32)
-        p_minus = perturb(p_plus, seed, -2.0 * mu + anchor, impl)  # w − μz
+        with jax.named_scope("theta_minus"):
+            # data-depend the second axpy on loss_plus so XLA cannot
+            # reorder the buffer chain (the scalar add is free).
+            anchor = (jnp.sum(loss_plus) * 0.0).astype(jnp.float32)
+            p_minus = perturb(p_plus, seed, -2.0 * mu + anchor,
+                              impl)                    # w − μz
         loss_minus = loss_fn(p_minus)
         return loss_plus, loss_minus, p_minus
     if mode == "fresh":
-        loss_plus = loss_fn(perturb(params, seed, mu, impl))
-        loss_minus = loss_fn(perturb(params, seed, -mu, impl))
+        with jax.named_scope("theta_plus"):
+            p_plus = perturb(params, seed, mu, impl)
+        loss_plus = loss_fn(p_plus)
+        with jax.named_scope("theta_minus"):
+            p_minus = perturb(params, seed, -mu, impl)
+        loss_minus = loss_fn(p_minus)
         return loss_plus, loss_minus, params
     if mode == "fused":
         # Perturbed weights never materialize tree-wide: consumers
@@ -159,9 +171,12 @@ def dual_forward(loss_fn: Callable[[PyTree], jnp.ndarray], params: PyTree,
         # matmul/gather, resolving at most one layer-sized transient.
         # Both rollouts run under ONE vmap over eps = (+μ, −μ): z depends
         # only on (seed, off) — never eps — so each leaf's z is generated
-        # once per round and shared by the two rollouts.
+        # once per round and shared by the two rollouts. The one vmapped
+        # tag serves both, under `theta_plus`.
         def one_rollout(eps):
-            return loss_fn(tag_perturbed(params, seed, eps))
+            with jax.named_scope("theta_plus"):
+                tagged = tag_perturbed(params, seed, eps)
+            return loss_fn(tagged)
 
         lpm = jax.vmap(one_rollout)(jnp.asarray([mu, -mu], jnp.float32))
         return lpm[0], lpm[1], params
@@ -182,12 +197,13 @@ def apply_update(params_at: PyTree, seed, p_hat: jnp.ndarray,
 
     chained: params_at = w−μz ⇒ one fused axpy of (μ − η p̂)·z restores and
     updates simultaneously. fresh: params_at = w ⇒ axpy of (−η p̂)·z.
+    Runs under the named scope `theta_update`.
     """
-    if mode == "chained":
-        return perturb(params_at, seed, mu - lr * p_hat, impl)
-    if mode in ("fresh", "fused"):
-        return perturb(params_at, seed, -lr * p_hat, impl)
-    raise ValueError(f"unknown dual mode: {mode}")
+    if mode not in ("chained", "fresh", "fused"):
+        raise ValueError(f"unknown dual mode: {mode}")
+    with jax.named_scope("theta_update"):
+        scale = mu - lr * p_hat if mode == "chained" else -lr * p_hat
+        return perturb(params_at, seed, scale, impl)
 
 
 # ---------------------------------------------------------------------------

@@ -82,20 +82,22 @@ def forward(params: dict, cfg: ModelConfig, tokens: jnp.ndarray, *,
     are prepended (VLM stub frontend). inputs_embeds ([B, S, D]) replaces
     the embedding lookup entirely (tokens may be None) — the continuous
     input surface gradient-inversion attacks (repro.privacy.attacks) and
-    soft-token methods differentiate through."""
-    x = L.embed(params["embed"], tokens) if inputs_embeds is None \
-        else inputs_embeds
-    if prefix_embeds is not None:
-        x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
-    positions = jnp.arange(x.shape[1])
+    soft-token methods differentiate through. Runs under the named scope
+    `forward_blocks`: the embedding, the layer scan and the final norm."""
+    with jax.named_scope("forward_blocks"):
+        x = L.embed(params["embed"], tokens) if inputs_embeds is None \
+            else inputs_embeds
+        if prefix_embeds is not None:
+            x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
+        positions = jnp.arange(x.shape[1])
 
-    def body(h, bp):
-        h, _ = _block_apply(bp, h, positions, cfg, cache=None,
-                            cache_pos=None, impl=impl)
-        return h, None
+        def body(h, bp):
+            h, _ = _block_apply(bp, h, positions, cfg, cache=None,
+                                cache_pos=None, impl=impl)
+            return h, None
 
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    return L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x, _ = jax.lax.scan(body, x, params["blocks"])
+        return L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
 
 def logits_from_hidden(params: dict, x: jnp.ndarray) -> jnp.ndarray:
@@ -108,18 +110,22 @@ def token_nll(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
               prefix_embeds: Optional[jnp.ndarray] = None,
               inputs_embeds: Optional[jnp.ndarray] = None,
               impl: Optional[str] = None) -> jnp.ndarray:
-    """Per-sequence-row mean NLL: [B, S] → [B]. (f32 CE over sharded vocab.)"""
+    """Per-sequence-row mean NLL: [B, S] → [B]. (f32 CE over sharded vocab.)
+    The LM head and the cross entropy run under the named scope
+    `lm_head_ce`."""
     x = forward(params, cfg, tokens, prefix_embeds=prefix_embeds,
                 inputs_embeds=inputs_embeds, impl=impl)
     if prefix_embeds is not None:
         x = x[:, prefix_embeds.shape[1]:]
-    logits = logits_from_hidden(params, x)                  # [B, S, V] f32
-    return L.cross_entropy(logits, targets, mask)
+    with jax.named_scope("lm_head_ce"):
+        logits = logits_from_hidden(params, x)              # [B, S, V] f32
+        return L.cross_entropy(logits, targets, mask)
 
 
 def loss_per_client(params: dict, cfg: ModelConfig, batch: dict, *,
                     impl: Optional[str] = None) -> jnp.ndarray:
-    """batch tokens/targets/mask: [K, b, S] → per-client losses [K]."""
+    """batch tokens/targets/mask: [K, b, S] → per-client losses [K]. The
+    per-client mean joins the LM head's scope, `lm_head_ce`."""
     k, b, s = batch["tokens"].shape
     flat = lambda a: a.reshape((k * b,) + a.shape[2:])
     prefix = batch.get("prefix_embeds")
@@ -127,7 +133,8 @@ def loss_per_client(params: dict, cfg: ModelConfig, batch: dict, *,
                     flat(batch["targets"]), flat(batch["mask"]),
                     prefix_embeds=flat(prefix) if prefix is not None else None,
                     impl=impl)
-    return jnp.mean(nll.reshape(k, b), axis=-1)
+    with jax.named_scope("lm_head_ce"):
+        return jnp.mean(nll.reshape(k, b), axis=-1)
 
 
 # ---------------------------------------------------------------------------

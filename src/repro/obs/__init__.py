@@ -8,10 +8,11 @@ bit-exact historical program — pinned in tests/test_obs.py):
      `ChunkPrefetcher` kick/stall, chunk prep, `BatchStager`,
      `AsyncCheckpointer` snapshot/write, schedule solves, dispatch and
      metric flushes; exported as Chrome trace-event JSON
-     (`train.py --trace-out trace.json`, loadable in Perfetto). Per-chunk
-     stall spans use the SAME perf_counter endpoints as the legacy
-     `prep_stall_s`/`ckpt_stall_s` scalars, which are kept as derived
-     sums.
+     (`train.py --trace-out trace.json`, loadable in Perfetto), and
+     mirrored as profiler annotations into any running `jax.profiler`
+     capture. Per-chunk stall spans use the SAME perf_counter endpoints
+     as the legacy `prep_stall_s`/`ckpt_stall_s` scalars, which are kept
+     as derived sums.
   2. **Compilation & memory watermarks** (`repro.obs.retrace`,
      `repro.obs.memory`) — build/retrace counters inside the memoized
      step/executor factories (surfaced as `RunResult.compile_stats`; a
@@ -80,6 +81,10 @@ class Telemetry:
                  memory: Optional[MemoryWatermark] = None,
                  cost: bool = False):
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        if self.tracer.enabled and self.tracer.annotate is None:
+            # a recording tracer mirrors its spans into any running
+            # jax.profiler capture (the null tracer opens nothing)
+            self.tracer.annotate = profile.trace_annotation
         self.memory = memory
         self.cost = bool(cost)
 

@@ -16,6 +16,10 @@ analyses of the memoized executors, never from running anything:
     spanning all client shards" — and gives the mesh-regression
     investigation per-collective numbers.
 
+`op_scopes` maps each top-level instruction of a compiled module (what a
+device trace shows as an op) to the program phase it belongs to: the
+innermost of the round step's named scopes (`SCOPES`) in its metadata.
+
 Everything here is AOT: `analyze_executor` lowers the executor's own
 program for the shapes the driver actually dispatched (specs captured
 before donation) under `retrace.suspended()`, so the compile-watermark
@@ -27,7 +31,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
@@ -115,6 +119,146 @@ def custom_call_census(hlo_text: str) -> Dict[str, int]:
     for m in _CUSTOM_CALL_RE.finditer(hlo_text):
         census[m.group(1)] = census.get(m.group(1), 0) + 1
     return census
+
+
+# the round step's named scopes (core/zo, core/pairzero, models/transformer)
+SCOPES = ("theta_plus", "theta_minus", "theta_update", "forward_blocks",
+          "lm_head_ce", "ota_aggregate")
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# computations whose instructions run as ops of their own: loop bodies and
+# conditions, conditional branches, called computations
+_CONTROL_RE = re.compile(
+    r"(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+_CALL_RE = re.compile(r"to_apply=%?([\w.\-]+)")
+_WRAPPED_RE = re.compile(r"^[\w\-]+\((.*)\)$")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+# opcodes that run nothing on the device: they keep their own scope
+_NO_WORK = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
+
+
+def opcode(rhs: str) -> str:
+    """The opcode of an instruction's right-hand side (`shape opcode(...)`),
+    skipping a tuple shape's parentheses."""
+    i = 0
+    if rhs.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        i += 1
+    rest = rhs[i:].lstrip()
+    if i == 0:
+        rest = rest.split(" ", 1)[1] if " " in rest else ""
+    return rest.split("(", 1)[0].strip()
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost of `SCOPES` in an op_name path, or None. Transform
+    wrappers (`vmap(theta_plus)`, `jvp(...)`) are looked through."""
+    for part in reversed(op_name.split("/")):
+        m = _WRAPPED_RE.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED_RE.match(part)
+        if part in SCOPES:
+            return part
+    return None
+
+
+def computations(hlo_text: str
+                 ) -> Tuple[Dict[str, List[Tuple[str, str]]], Optional[str]]:
+    """({computation: [(instruction, right-hand side)]}, entry name) of a
+    module's text."""
+    comps: Dict[str, List[Tuple[str, str]]] = {}
+    entry = None
+    current: Optional[List[Tuple[str, str]]] = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m and not line.startswith((" ", "\t")):
+            current = comps.setdefault(m.group(2), [])
+            if m.group(1):
+                entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if m and current is not None:
+            current.append((m.group(1), m.group(2)))
+    return comps, entry
+
+
+def op_name(rhs: str) -> Optional[str]:
+    """The `op_name` metadata of an instruction's right-hand side."""
+    m = _OP_NAME_RE.search(rhs)
+    return m.group(1) if m else None
+
+
+def _inherit(insts: List[Tuple[str, str]],
+             scopes: Dict[str, Optional[str]]) -> None:
+    """Give each instruction of one computation that does work but has no
+    metadata (the copies the compiler inserts for layouts and loop
+    carries) the scope of its first scoped operand, else of its first
+    scoped user."""
+    local = {name for name, _ in insts}
+    operands = {name: [o for o in _OPERAND_RE.findall(rhs.split("),")[0])
+                       if o in local and o != name]
+                for name, rhs in insts}
+    users: Dict[str, List[str]] = {name: [] for name in local}
+    for name, ops in operands.items():
+        for o in ops:
+            users[o].append(name)
+    bare = [name for name, rhs in insts
+            if op_name(rhs) is None and opcode(rhs) not in _NO_WORK]
+    changed = True
+    while changed:
+        changed = False
+        for name in bare:
+            if scopes[name] is None:
+                near = [scopes[o] for o in operands[name] + users[name]]
+                scope = next((x for x in near if x is not None), None)
+                if scope is not None:
+                    scopes[name] = scope
+                    changed = True
+
+
+def op_scopes(hlo_text: str) -> Dict[str, Optional[str]]:
+    """{instruction name: scope} for every top-level instruction of a
+    compiled module's text (`compiled.as_text()`): those of the entry
+    computation and of the loop bodies, conditions, branches and called
+    computations reachable from it — not those inside fusions or
+    reducers. The scope is `scope_of` the instruction's `op_name`
+    metadata, None where it has none of `SCOPES`; an instruction with no
+    metadata at all takes its operand's scope, or its user's."""
+    comps, entry = computations(hlo_text)
+    out: Dict[str, Optional[str]] = {}
+    todo = [entry] if entry is not None else []
+    seen = set(todo)
+    while todo:
+        comp = comps.get(todo.pop(), [])
+        for name, rhs in comp:
+            op = opcode(rhs)
+            meta = op_name(rhs)
+            out[name] = scope_of(meta) if meta else None
+            called = []
+            for cm in _CONTROL_RE.finditer(rhs):
+                if cm.group(1):
+                    called.append(cm.group(1))
+                else:
+                    called.extend(c.strip().lstrip("%")
+                                  for c in cm.group(2).split(","))
+            if op == "call":
+                called.extend(_CALL_RE.findall(rhs))
+            for c in called:
+                if c and c not in seen:
+                    seen.add(c)
+                    todo.append(c)
+        _inherit(comp, out)
+    return out
 
 
 @dataclass

@@ -8,9 +8,10 @@ trilemma-ledger row.
 
 Two sources, best first:
 
-  * `device.memory_stats()["peak_bytes_in_use"]` — the allocator's own
-    high-water mark, when the backend reports one (TPU/GPU; CPU returns
-    None);
+  * `device.memory_stats()` — the fullest device's `peak_bytes_in_use`
+    (buffers) plus `peak_bytes_reserved` (the region held for programs'
+    temporaries), when the backend reports them (TPU/GPU; CPU returns
+    None) — the reading of the benchmark's `peak_hbm_gb`;
   * sum of `a.nbytes` over `jax.live_arrays()` — live-buffer bytes at the
     sample instant (always available; an instantaneous view, so the
     boundary cadence is what makes it a useful watermark).
@@ -51,15 +52,19 @@ def live_buffer_bytes(arrays=None) -> int:
 
 
 def device_peak_bytes() -> Optional[int]:
-    """Allocator high-water mark summed over devices, or None when the
-    backend (e.g. CPU) reports no memory stats."""
-    total, seen = 0, False
+    """What the runtime has held at its peak on the fullest device:
+    buffers (`peak_bytes_in_use`) plus the region it reserves for
+    programs' temporaries (`peak_bytes_reserved`, which the buffer count
+    leaves out on TPU), or None when the backend (e.g. CPU) reports no
+    memory stats."""
+    peak = None
     for dev in jax.devices():
         stats = dev.memory_stats()
         if stats and "peak_bytes_in_use" in stats:
-            total += int(stats["peak_bytes_in_use"])
-            seen = True
-    return total if seen else None
+            held = int(stats["peak_bytes_in_use"]) \
+                + int(stats.get("peak_bytes_reserved", 0))
+            peak = held if peak is None else max(peak, held)
+    return peak
 
 
 class MemoryWatermark:

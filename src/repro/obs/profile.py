@@ -19,8 +19,12 @@ records host-side truth (chunk_prep / dispatch / prep_stall) on a
   3. `Tracer.export_chrome(..., extra_events=...)` appends them — host
      spans and XLA ops on ONE timeline (`train.py --profile-out`).
 
-Opt-in and strictly additive: without `--profile-out` nothing here is
-imported into the hot path, and the run's numerics are untouched either
+The capture itself holds the tracer's spans as well: `trace_annotation`
+is the factory a recording tracer mirrors each span through, so inside any
+`jax.profiler` capture the spans sit on their threads' host lines, on the
+device planes' own clock, with no anchor needed.
+
+Opt-in and strictly additive: the run's numerics are untouched either
 way (the profiler observes; it never reschedules).
 """
 from __future__ import annotations
@@ -32,6 +36,19 @@ import os
 import tempfile
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+
+def trace_annotation(name: str, step_num: Optional[int],
+                     args: Dict[str, Any]):
+    """The annotation factory a recording `Tracer` mirrors its spans
+    through: a `jax.profiler.TraceAnnotation` (a `StepTraceAnnotation`
+    numbered `step_num` when given), with the span's arguments as its
+    stats. Cheap when no capture runs; inside one, the span lands on its
+    thread's line of the host plane, on the device planes' clock."""
+    import jax
+    if step_num is None:
+        return jax.profiler.TraceAnnotation(name, **args)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num, **args)
 
 
 class ProfilerSession:

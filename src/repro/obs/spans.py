@@ -25,7 +25,22 @@ import json
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
+
+# annotate(name, step_num or None, args) -> a context manager open for the
+# span's life
+Annotate = Callable[[str, Optional[int], Dict[str, Any]], ContextManager]
+
+
+class _NullContext:
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_CTX = _NullContext()
 
 
 class Tracer:
@@ -35,6 +50,7 @@ class Tracer:
     that measures its own interval reports it verbatim via `add_span`.
     `events()` returns host-side dicts (seconds, float) for tests and
     derived sums; `export_chrome` writes the Perfetto-loadable JSON.
+    `annotate`, when set, mirrors every span into the profiler's trace.
     """
 
     def __init__(self):
@@ -42,6 +58,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._threads: Dict[int, str] = {}
+        self.annotate: Optional[Annotate] = None
 
     # -- recording --------------------------------------------------------
     def _tid(self) -> int:
@@ -58,14 +75,36 @@ class Tracer:
                 "name": name, "ph": "X", "tid": self._tid(),
                 "ts": start - self._epoch, "dur": end - start, "args": args})
 
-    @contextmanager
-    def span(self, name: str, **args):
+    def annotation(self, name: str, **args) -> ContextManager:
+        """The profiler annotation of a span, without recording one: for
+        callers that take their own endpoints inside it and report them
+        with `add_span`. A no-op context without an annotation factory."""
+        return self._annotation(name, None, args)
+
+    def _annotation(self, name: str, step_num: Optional[int],
+                    args: Dict[str, Any]) -> ContextManager:
+        if self.annotate is None:
+            return _NULL_CTX
+        return self.annotate(name, step_num, args)
+
+    def span(self, name: str, **args) -> ContextManager:
         """Context manager recording the enclosed wall-clock interval."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_span(name, t0, time.perf_counter(), **args)
+        return self._timed(name, None, args)
+
+    def step_span(self, name: str, step_num: int, **args) -> ContextManager:
+        """`span`, annotated as profiler step `step_num` (the driver's
+        chunk loop: one step per chunk)."""
+        return self._timed(name, step_num, args)
+
+    @contextmanager
+    def _timed(self, name: str, step_num: Optional[int],
+               args: Dict[str, Any]):
+        with self._annotation(name, step_num, args):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add_span(name, t0, time.perf_counter(), **args)
 
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration marker (e.g. a prefetch kick)."""
@@ -152,21 +191,12 @@ class Tracer:
             json.dump(doc, f)
 
 
-class _NullContext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullContext()
-
-
 class NullTracer(Tracer):
     """No-op tracer: every instrumented call site stays a plain method
     call whether telemetry is on or off, so the telemetry-off program is
     structurally identical to the historical one (neutrality pin)."""
+
+    annotate = None
 
     def __init__(self):  # no lock, no buffers
         pass
@@ -179,7 +209,15 @@ class NullTracer(Tracer):
     def add_span(self, name, start, end, **args):
         """No-op."""
 
+    def annotation(self, name, **args):
+        """Shared no-op context manager: no profiler annotation."""
+        return _NULL_CTX
+
     def span(self, name, **args):
+        """Shared no-op context manager (no allocation per call)."""
+        return _NULL_CTX
+
+    def step_span(self, name, step_num, **args):
         """Shared no-op context manager (no allocation per call)."""
         return _NULL_CTX
 

@@ -14,7 +14,10 @@ Checks, in order:
      nested (contained or disjoint, never partially overlapping): the
      tracer records via nested context managers, so a violation means a
      broken clock. Merged device-op events are exempt (the runtime
-     overlaps executions by design).
+     overlaps executions by design). The chunk prep's inner spans
+     (`prep_device_wait`, `batch_gen`, `h2d_put`) must each lie inside a
+     `ctl_build` or `batch_stage` span on their lane: the prep's host
+     work and its device waits are told apart only there.
   3. Stall attribution — the sum of prep_stall (and ckpt_snapshot) span
      durations equals otherData's legacy prep_stall_s/ckpt_stall_s
      counters within --stall-tol seconds (default 1ms): spans are the
@@ -56,6 +59,9 @@ from collections import defaultdict
 
 REQUIRED_SPANS = ("chunk", "dispatch", "chunk_prep", "prep_stall",
                   "metrics_flush")
+# the chunk prep's inner spans and the spans they must nest in
+PREP_INNER = ("prep_device_wait", "batch_gen", "h2d_put")
+PREP_OUTER = ("ctl_build", "batch_stage")
 LEDGER_SCHEMA = "trilemma_ledger/v2"
 LEDGER_KEYS = ("round", "loss", "k_eff", "k_sync", "stale_frac",
                "bits_round", "bits_cum", "dp_cost", "dp_spent_cum",
@@ -120,6 +126,13 @@ def check_trace(doc, errors, stall_tol):
                     f"{stack[-1][1]:.1f}] on tid {tid}")
                 continue
             stack.append((a, b, nm))
+        outers = [(a, b) for a, b, nm in spans if nm in PREP_OUTER]
+        for a, b, nm in spans:
+            if nm in PREP_INNER and not any(
+                    oa - eps <= a and b <= ob + eps for oa, ob in outers):
+                errors.append(
+                    f"trace: {nm!r} span [{a:.1f}, {b:.1f}] on tid {tid} "
+                    f"lies in no {' or '.join(PREP_OUTER)} span")
 
     # 3. stall attribution ---------------------------------------------
     for span_name, scalar in (("prep_stall", "prep_stall_s"),
