@@ -142,8 +142,8 @@ def dual_forward(loss_fn: Callable[[PyTree], jnp.ndarray], params: PyTree,
 
     The θ passes run under the named scopes `theta_plus` / `theta_minus`
     (`apply_update`'s under `theta_update`), so every op of a pass — the
-    kernel and each leaf's pads, reshapes and slices — carries its phase
-    in the compiled program's metadata (`repro.obs.hlo.op_scopes`).
+    kernel and any copy XLA puts around it — carries its phase in the
+    compiled program's metadata (`repro.obs.hlo.op_scopes`).
     """
     if mode == "chained":
         with jax.named_scope("theta_plus"):

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 # Trailing dims that are a multiple of one SIMD packet (16 f32 covers both
 # AVX2 and AVX-512) vectorize log/cos without scalar tails, so the native-
-# shape evaluation is bitwise identical to the kernel's lane-tiled one.
+# shape evaluation is bitwise identical to the kernel's, which evaluates
+# blocks of the leaf's own [prod(shape[:-1]), shape[-1]] view.
 _SIMD_PACKET = 16
 
 
@@ -37,10 +38,10 @@ def draw_z_ref(shape, seed) -> jnp.ndarray:
       materializes), shards with the consumer under GSPMD, and compiles
       identically inside lax.scan and standalone jit (the engine bitwise
       invariant). No scalar libm tails, so it is bitwise equal to the
-      kernel's lane-tiled evaluation.
+      kernel's blockwise evaluation.
     * awkward trailing dim (e.g. [64, 50]): native evaluation has shape-
       dependent scalar libm tails — the historical 1-2 ulp pallas-interpret
-      drift. Evaluate on the kernel's canonical [rows, LANE] layout behind
+      drift. Evaluate on a canonical flat [rows, LANE] layout behind
       an optimization barrier so fusion cannot drag the transcendentals
       back into the consumer's iteration space (the barrier materializes z
       for these shapes — the price of bitwise stability off the lane grid).

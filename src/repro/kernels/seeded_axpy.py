@@ -15,12 +15,20 @@ kernel, the XLA fallback and the pure-jnp oracle (ref.py). That gives
   * exact MeZO chain algebra: w → w+μz → w−μz → restore+update reuses the
     very same z at every step from nothing but the int32 seed.
 
-Counters are element indices, so the stream is also invariant to tiling and
-sharding — a resharded or differently-blocked call perturbs identically.
+The kernel works on each leaf where it lies: the leaf is viewed as
+[prod(shape[:-1]), shape[-1]], a bitcast on the TPU's (8, 128) tiling, and
+a 2-D grid of blocks of about 1 MiB walks that view and writes each block
+back over the input (the output aliases w). No flat, padded or relaid-out
+copy of θ is made; a ragged last block is masked, not padded.
+
+Counters are flat row-major element indices, so the stream is also
+invariant to tiling and sharding — a resharded or differently-blocked call
+perturbs identically.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 LANE = 128
+SUBLANE = 8
 DEFAULT_BLOCK = 2048 * LANE  # elements per grid step (1 MiB of f32 in VMEM)
 
 _GOLDEN = 0x9E3779B9
@@ -71,52 +80,78 @@ def gaussian_from_counter(idx: jnp.ndarray, seed: jnp.ndarray) -> jnp.ndarray:
     return r * jnp.cos(jnp.float32(_TWO_PI) * u2)
 
 
-def _axpy_kernel(seed_ref, scale_ref, w_ref, o_ref, *, rows_per_block: int):
-    tile = pl.program_id(0)
+def _axpy_kernel(seed_ref, scale_ref, w_ref, o_ref, *, cols: int):
     rows, lanes = w_ref.shape
-    row0 = tile * rows_per_block
+    row0 = (pl.program_id(0) * rows).astype(jnp.uint32)
+    col0 = (pl.program_id(1) * lanes).astype(jnp.uint32)
     r_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 0)
-    l_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 1)
-    idx = (jnp.uint32(row0) + r_iota) * jnp.uint32(lanes) + l_iota
+    c_iota = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 1)
+    idx = (row0 + r_iota) * jnp.uint32(cols) + (col0 + c_iota)
     z = gaussian_from_counter(idx, seed_ref[0])
     w = w_ref[...].astype(jnp.float32)
     o_ref[...] = (w + scale_ref[0] * z).astype(o_ref.dtype)
+
+
+def _matrix_view(shape) -> tuple:
+    """[R, C] of a leaf in its own layout: the leading dims merged into the
+    second-minor one, which is a bitcast on the TPU's (8, 128) tiling.
+    A 1-D leaf of whole lanes takes [n / 128, 128]; any other 0-d or 1-D
+    leaf is one row."""
+    if len(shape) >= 2:
+        return math.prod(shape[:-1]), shape[-1]
+    n = math.prod(shape)
+    return (n // LANE, LANE) if n % LANE == 0 else (1, n)
+
+
+def _block_shape(rows: int, cols: int, block: int) -> tuple:
+    """(br, bc) of about `block` elements: whole rows while 8 of them fit,
+    else 8 rows of whole lanes. br is a multiple of 8 or all rows; bc all
+    columns or a multiple of 128."""
+    min_rows = min(rows, SUBLANE)
+    if min_rows * cols <= block:
+        br = max(SUBLANE, block // cols // SUBLANE * SUBLANE)
+        return min(br, rows), cols
+    return min_rows, max(LANE, block // min_rows // LANE * LANE)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def seeded_axpy_pallas(w: jnp.ndarray, seed: jnp.ndarray, scale,
                        block: int = DEFAULT_BLOCK,
                        interpret: bool = False) -> jnp.ndarray:
-    """out = w + scale * z(seed), flattened-and-tiled over a 1D grid.
+    """out = w + scale * z(seed), in place on the leaf's own layout.
+
+    The leaf is viewed as [R, C] (`_matrix_view`, a bitcast) and tiled by
+    a 2-D grid of blocks of about `block` elements (`_block_shape`); a
+    ragged last block in either dimension is masked by Pallas, never
+    padded. The counter of an element is its flat row-major index, so z
+    is the stream of `ref.draw_z_ref`. The output aliases `w`: each block
+    is read and then written at the same index, so a donated `w` is
+    updated with no copy.
 
     Args:
-      w: any-shape array (flattened internally; padded to the lane width).
+      w: any-shape array.
       seed: uint32/int32 scalar (fold leaf/round indices in *before* calling).
       scale: traced or static scalar.
+      block: elements per grid step.
     """
-    orig_shape, orig_dtype = w.shape, w.dtype
-    n = w.size
-    padded = max(((n + block - 1) // block) * block, 8 * LANE)
-    flat = jnp.ravel(w)
-    if padded != n:
-        flat = jnp.pad(flat, (0, padded - n))
-    grid = max(padded // block, 1)
-    rows_per_block = padded // grid // LANE
-    mat = flat.reshape(grid * rows_per_block, LANE)
-
+    rows, cols = _matrix_view(w.shape)
+    br, bc = _block_shape(rows, cols, block)
+    mat = w.reshape(rows, cols)
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     out = pl.pallas_call(
-        functools.partial(_axpy_kernel, rows_per_block=rows_per_block),
-        grid=(grid,),
+        functools.partial(_axpy_kernel, cols=cols),
+        grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((rows_per_block, LANE), lambda i: (i, 0)),
+            spec,
         ],
-        out_specs=pl.BlockSpec((rows_per_block, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(mat.shape, orig_dtype),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(mat.shape, w.dtype),
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray([seed]).astype(jnp.uint32),
       jnp.asarray([scale], jnp.float32), mat)
-    return out.reshape(-1)[:n].reshape(orig_shape)
+    return out.reshape(w.shape)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, seeded_axpy
 
 jax.config.update("jax_enable_x64", False)
 
@@ -34,6 +34,54 @@ def test_seeded_axpy_z_stream_bitwise(shape):
     z_xla = ops.seeded_axpy(zeros, 7, 1.0, impl="xla")
     assert np.array_equal(np.asarray(z_ref), np.asarray(z_pl))
     assert np.array_equal(np.asarray(z_ref), np.asarray(z_xla))
+
+
+# (shape, block, grid): edges of the 2-D grid over the leaf's own layout
+AXPY_EDGES = [((1000, 768), 16 * 768, (63, 1)),    # ragged last row block
+              ((16, 1000), 2048, (2, 4)),          # column blocks, ragged
+              ((3, 40, 256), 16 * 256, (8, 1))]    # stacked 3-D leaf
+
+
+@pytest.mark.parametrize("shape,block,grid", AXPY_EDGES,
+                         ids=["ragged_rows", "column_blocks", "stacked"])
+def test_seeded_axpy_pallas_edges_match_ref(shape, block, grid):
+    """Each edge case really is one (its grid), and the kernel equals the
+    ref there bit for bit: the z stream itself and the axpy on random w."""
+    rows, cols = seeded_axpy._matrix_view(shape)
+    br, bc = seeded_axpy._block_shape(rows, cols, block)
+    assert (-(-rows // br), -(-cols // bc)) == grid
+    zeros = jnp.zeros(shape, jnp.float32)
+    z_pl = seeded_axpy.seeded_axpy_pallas(zeros, jnp.uint32(7), 1.0,
+                                          block=block, interpret=True)
+    assert np.array_equal(np.asarray(z_pl),
+                          np.asarray(ref.seeded_axpy_ref(zeros, 7, 1.0)))
+    assert np.array_equal(np.asarray(z_pl),
+                          np.asarray(ops.seeded_axpy(zeros, 7, 1.0,
+                                                     impl="xla")))
+    w = jax.random.normal(jax.random.key(0), shape, jnp.float32)
+    o_pl = seeded_axpy.seeded_axpy_pallas(w, jnp.uint32(42), 0.25,
+                                          block=block, interpret=True)
+    assert np.array_equal(np.asarray(o_pl),
+                          np.asarray(ref.seeded_axpy_ref(w, 42, 0.25)))
+
+
+def test_seeded_axpy_pallas_donated_walk_is_bitwise():
+    """The chained +μ, −2μ, +μ walk updates θ in place under donation and
+    gives the bits of the walk that keeps its input."""
+    mu = 1e-3
+
+    def walk(w):
+        for scale in (mu, -2 * mu, mu):
+            w = seeded_axpy.seeded_axpy_pallas(w, jnp.uint32(9), scale,
+                                               block=16 * 384,
+                                               interpret=True)
+        return w
+
+    w = np.asarray(jax.random.normal(jax.random.key(1), (2, 40, 384)))
+    donated = jax.jit(walk, donate_argnums=0)(jnp.asarray(w))
+    kept = jax.jit(walk)(jnp.asarray(w))
+    assert np.array_equal(np.asarray(donated), np.asarray(kept))
+    np.testing.assert_allclose(np.asarray(donated), w, atol=1e-5)
 
 
 def test_seeded_axpy_deterministic_and_seed_sensitive():

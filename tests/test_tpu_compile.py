@@ -10,6 +10,9 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the worker that runs
 this file keeps it until it exits. Keep every such compile in this file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -59,6 +62,38 @@ def test_seeded_axpy_compiles_for_v5e(one_chip, shape):
         _spec(one_chip, shape), _spec(one_chip, (), jnp.uint32),
         _spec(one_chip, ()))
     assert "tpu_custom_call" in text
+
+
+def _leaf_opcodes(text: str, shape) -> set:
+    """Opcodes of the entry computation's instructions that hold a value
+    of the leaf's size."""
+    n = math.prod(shape)
+    entry = text[text.index("\nENTRY"):]
+    found = set()
+    for m in re.finditer(r"%\S+ = (.*?) ([a-z][a-z-]*)\(", entry):
+        dims = re.findall(r"\[([\d,]*)\]", m.group(1))
+        if any(math.prod(int(d) for d in ds.split(",")) == n
+               for ds in dims if ds):
+            found.add(m.group(2))
+    return found
+
+
+@pytest.mark.parametrize("shape", [(8, 4096, 11008), (64000, 4096),
+                                   (12, 768, 768), (12, 768), (50272, 768)],
+                         ids=["yi_stacked_ffn", "yi_embedding",
+                              "opt_stacked_attn", "opt_gains",
+                              "opt_embedding"])
+def test_seeded_axpy_updates_in_place_for_v5e(one_chip, shape):
+    """With θ donated, the kernel reads and writes the leaf where it lies:
+    nothing but bitcasts around the custom call, no copy, pad, slice or
+    relayout of the leaf."""
+    text = jax.jit(lambda w, seed, scale: seeded_axpy_pallas(w, seed, scale),
+                   donate_argnums=0).lower(
+        _spec(one_chip, shape), _spec(one_chip, (), jnp.uint32),
+        _spec(one_chip, ())).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _leaf_opcodes(text, shape) - {"bitcast"} == {"parameter",
+                                                        "custom-call"}
 
 
 def _pmm_specs(one_chip, m, k, n):
