@@ -1,19 +1,22 @@
 """One benchmark cell: its entry in BENCHMARK.json, its configuration file
-and its traffic file, found by name.
+and its traffic file, found by name; and the model families.
 
 A cell names a configuration (`bench/configs/<config>.json`, the file that
 BENCHMARK.json's `configs` entry gives) and a traffic mix
-(`bench/traffic/<traffic>.json`). Adding a cell of a known model family
-and task adds files and entries; nothing here changes. The weights'
-layout (weights.py), the reference (reference.py) and the FLOP counts
-(counts.py) know one family, the dense decoder, and the traffic generator
-the tasks of traffic_gen.SAMPLERS: another family or task is code, and
-comes with a benchmark PR.
+(`bench/traffic/<traffic>.json`). The configuration names its model
+family, one file `bench/families/<family>.py` that gives the sizes, the
+weights' layout, the reference's loss and the FLOP counts of that family
+(the contract is in bench/families/dense_decoder.py's docstring). Adding a
+cell, a configuration or a family adds files and entries; nothing here
+changes. A new task is shared code: its sampler in traffic_gen.SAMPLERS and
+its scored positions in counts.SCORED.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -24,8 +27,10 @@ ROOT = BENCH.parent
 # the program keeps its seeds in int32 (`zo.round_seed` converts through
 # jnp.asarray); a larger --seed is folded into this range for the program
 PROGRAM_SEED_MOD = 2 ** 31 - 1
-# model families with a weight layout, a plain reference and FLOP counts
-FAMILIES = ("dense_decoder",)
+FAMILY_DIR = BENCH / "families"
+# model families with a weight layout, a plain reference and FLOP counts:
+# the files of bench/families/
+FAMILIES = tuple(sorted(p.stem for p in FAMILY_DIR.glob("*.py")))
 
 
 @dataclass(frozen=True)
@@ -75,6 +80,16 @@ def load(workload: str, root: Optional[Path] = None) -> Cell:
                 limits=_read(limits_path) if limits_path.exists() else None)
 
 
+@functools.lru_cache(maxsize=None)
+def family(name: str):
+    """bench/families/<name>.py, loaded by file name."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{name}", FAMILY_DIR / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def program_seed(seed: int) -> int:
     """The seed handed to the program: --seed folded into int32 range."""
     return int(seed) % PROGRAM_SEED_MOD
@@ -104,7 +119,7 @@ def program_configs(cell: Cell, seed: int):
     from repro.launch import train as train_cli
     args = train_cli.build_parser().parse_args(train_argv(cell, seed))
     cfg, pz, _ = train_cli.make_configs(args)
-    cfg = dataclasses.replace(cfg, **cell.config.get("model", {}))
+    cfg = override(cfg, cell.config.get("model", {}))
     n_params = cfg.param_count()
     if n_params != cell.config["params"]:
         raise SystemExit(f"{cell.config['name']}: the program counts "
@@ -113,6 +128,16 @@ def program_configs(cell: Cell, seed: int):
     pz = dataclasses.replace(
         pz, channel=dataclasses.replace(pz.channel, d=n_params))
     return cfg, pz
+
+
+def override(obj, changes: Dict[str, Any]):
+    """`dataclasses.replace(obj, **changes)`, where a dict given for a field
+    that is itself a dataclass replaces that dataclass field by field:
+    {"moe": {"n_experts": 8}} builds a new MoEConfig."""
+    return dataclasses.replace(obj, **{
+        k: override(getattr(obj, k), v)
+        if isinstance(v, dict) and dataclasses.is_dataclass(getattr(obj, k))
+        else v for k, v in changes.items()})
 
 
 def parse_args(argv=None) -> argparse.Namespace:

@@ -5,12 +5,20 @@ rollouts per client, each over K·B sequences of S tokens, counting the
 projection and MLP matmuls at every position, causal attention at the
 model's own head dim, and the LM head only where the task scores a
 position. The z draw, padding and any recompute are not counted.
+
+The model's FLOPs and its attention's FLOPs and bytes are counted by its
+family (bench/families/<family>.py, named by m["family"]); what every
+family shares stays here: the round's shape, the positions each task
+scores, the θ update's bytes and the chip's peaks. A new task is a new
+entry of SCORED.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 from typing import Any, Dict
+
+import cell
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 # positions each task scores per sequence (None: every position)
@@ -28,30 +36,18 @@ def peaks(device_kind: str) -> Dict[str, Any]:
     return table[device_kind]
 
 
-def _shape(m: Dict[str, Any], traffic: Dict[str, Any]):
+def round_shape(traffic: Dict[str, Any]):
+    """(sequences, tokens per sequence, rollouts) of one round: K·B
+    sequences of S tokens, two rollouts per perturbation direction."""
     seqs = int(traffic["clients"]) * int(traffic["batch"])
     s = int(traffic["seq_len"])
     rollouts = 2 * int(traffic["n_perturb"])
     return seqs, s, rollouts
 
 
-def attention_flops_per_seq(m: Dict[str, Any], s: int) -> float:
-    """Causal QKᵀ and PV over the S(S+1)/2 visible pairs, all layers."""
-    return m["layers"] * m["heads"] * 2.0 * 2.0 * m["head_dim"] \
-        * s * (s + 1) / 2.0
-
-
 def round_flops(m: Dict[str, Any], traffic: Dict[str, Any]) -> float:
-    seqs, s, rollouts = _shape(m, traffic)
-    d, hd = m["d"], m["head_dim"]
-    q, kv = m["heads"] * hd, m["kv_heads"] * hd
-    per_token = 2.0 * m["layers"] * (d * q + 2 * d * kv + q * d
-                                     + 3 * d * m["d_ff"])
-    scored = SCORED[traffic["task"]]
-    scored = s if scored is None else scored
-    head = 2.0 * d * m["vocab"] * scored
-    per_seq = per_token * s + attention_flops_per_seq(m, s) + head
-    return rollouts * seqs * per_seq
+    """Model FLOPs of one round, as the model's family counts them."""
+    return cell.family(m["family"]).round_flops(m, traffic)
 
 
 def axpy_bytes(n_params: int, traffic: Dict[str, Any]) -> float:
@@ -61,13 +57,11 @@ def axpy_bytes(n_params: int, traffic: Dict[str, Any]) -> float:
 
 
 def attention_flops(m: Dict[str, Any], traffic: Dict[str, Any]) -> float:
-    seqs, s, rollouts = _shape(m, traffic)
-    return rollouts * seqs * attention_flops_per_seq(m, s)
+    """The attention kernel's FLOPs in one round, by the family's count."""
+    return cell.family(m["family"]).attention_flops(m, traffic)
 
 
 def attention_bytes(m: Dict[str, Any], traffic: Dict[str, Any]) -> float:
-    """q, k, v read and o written once, float32, at the model's head dim."""
-    seqs, s, rollouts = _shape(m, traffic)
-    per_layer = (2 * m["heads"] + 2 * m["kv_heads"]) * s * m["head_dim"] \
-        * F32
-    return rollouts * seqs * m["layers"] * per_layer
+    """The attention kernel's q/k/v/o bytes in one round, by the family's
+    count."""
+    return cell.family(m["family"]).attention_bytes(m, traffic)

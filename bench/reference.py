@@ -1,4 +1,4 @@
-"""Plain reference of a federated ZO round on a dense decoder.
+"""Plain reference of a federated ZO round.
 
 Written from the published description of the method and of the system's
 documented seeded streams; it imports nothing of the program and takes no
@@ -12,14 +12,12 @@ array the program made. One round, for every perturbation direction j:
 
 with c(t) the Theorem-3 power-control schedule solved over the horizon
 from a Rayleigh channel draw, σ = 0, and the receiver noise ζ drawn from
-the round's key. The model is a pre-norm decoder: RMSNorm, rotary
-attention with grouped KV heads, SwiGLU, untied head, masked mean
-cross-entropy per row, averaged over each client's rows.
+the round's key. The round, its streams and the shared pieces of a model
+(`perturbed`, `mm`, `rmsnorm`, `rope`) are here; the model's loss is its
+family's (bench/families/<family>.py, `make_loss`).
 
 `dtype`/`precision` select the arithmetic: float32 at HIGHEST is the
 reference; bfloat16 is the control that a correct limit has to reject.
-Perturbed weights are formed one layer at a time inside the layer scan,
-so the reference holds θ plus one layer's transient.
 """
 from __future__ import annotations
 
@@ -29,6 +27,8 @@ from typing import Any, Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import cell
 
 PyTree = Any
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -155,22 +155,22 @@ def analog_schedule(seed: int, horizon: int, k: int, *, power: float,
     return c_of(hi)
 
 
-# --- the model -----------------------------------------------------------------
+# --- pieces every family's model shares --------------------------------------
 
-def _mm(x, w, spec, dtype, precision):
+def mm(x, w, spec, dtype, precision):
     out = jnp.einsum(spec, x.astype(dtype), w.astype(dtype),
                      precision=precision,
                      preferred_element_type=jnp.float32)
     return out
 
 
-def _rmsnorm(x, g, eps):
+def rmsnorm(x, g, eps):
     xf = x.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return xf * r * g.astype(jnp.float32)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: [B, H, S, D]; rotate the two halves of D by position."""
     s, d = x.shape[-2], x.shape[-1]
     half = d // 2
@@ -182,83 +182,21 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+def perturbed(w, seed, eps, dtype, offset=0):
+    """w + eps z in `dtype`, z the stream `seed` from flat index `offset`:
+    a layer of a scan-stacked leaf starts at layer × its size."""
+    z = z_block(w.shape, seed, offset)
+    return (w.astype(jnp.float32) + eps * z).astype(dtype)
+
+
 def make_loss(m: Dict[str, Any], leaf_paths: Sequence[str],
               dtype=jnp.float32, precision=HIGHEST):
-    """loss(θ_leaves, seeds, eps, tokens, targets, mask) → [K] at θ + eps z.
+    """loss(θ_leaves, seeds, eps, tokens, targets, mask) → [K] at θ + eps z,
+    the model family's (bench/families/<family>.py).
 
     θ_leaves are in flatten order; seeds are the per-leaf stream seeds."""
-    idx = {p: i for i, p in enumerate(leaf_paths)}
-    hd, hq, hkv = m["head_dim"], m["heads"], m["kv_heads"]
-    eps_norm, theta = m["norm_eps"], m["rope_theta"]
-    act = dtype
-
-    def perturbed(w, seed, eps, offset=0):
-        z = z_block(w.shape, seed, offset)
-        return (w.astype(jnp.float32) + eps * z).astype(dtype)
-
-    def loss(leaves, seeds, eps, tokens, targets, mask):
-        k, b, s = tokens.shape
-        tok = tokens.reshape(k * b, s)
-        emb = perturbed(leaves[idx["embed/w"]], seeds[idx["embed/w"]], eps)
-        x = jnp.take(emb, tok, axis=0).astype(act)
-        block_names = [p for p in leaf_paths if p.startswith("blocks/")]
-        stacked = [leaves[idx[p]] for p in block_names]
-        layer_ids = jnp.arange(stacked[0].shape[0], dtype=jnp.uint32)
-
-        def layer(h, xs):
-            li, ws = xs
-            w = {}
-            for name, wl in zip(block_names, ws):
-                stride = int(np.prod(wl.shape))
-                w[name] = perturbed(wl, seeds[idx[name]], eps,
-                                    li * jnp.uint32(stride & 0xFFFFFFFF))
-            a = _rmsnorm(h, w["blocks/ln1/g"], eps_norm).astype(act)
-            q = _mm(a, w["blocks/attn/wq"], "bsd,df->bsf", dtype, precision)
-            kk = _mm(a, w["blocks/attn/wk"], "bsd,df->bsf", dtype, precision)
-            v = _mm(a, w["blocks/attn/wv"], "bsd,df->bsf", dtype, precision)
-            q = q.astype(act).reshape(k * b, s, hq, hd).swapaxes(1, 2)
-            kk = kk.astype(act).reshape(k * b, s, hkv, hd).swapaxes(1, 2)
-            v = v.astype(act).reshape(k * b, s, hkv, hd).swapaxes(1, 2)
-            q = _rope(q.astype(jnp.float32), theta).astype(act)
-            kk = _rope(kk.astype(jnp.float32), theta).astype(act)
-            group = hq // hkv
-            kk = jnp.repeat(kk, group, axis=1)
-            v = jnp.repeat(v, group, axis=1)
-            sc = jnp.einsum("bhqd,bhkd->bhqk", q, kk, precision=precision,
-                            preferred_element_type=jnp.float32) / math.sqrt(hd)
-            causal = jnp.tril(jnp.ones((s, s), bool))
-            sc = jnp.where(causal[None, None], sc, -jnp.inf)
-            pr = jax.nn.softmax(sc, axis=-1)
-            o = jnp.einsum("bhqk,bhkd->bhqd", pr.astype(act), v,
-                           precision=precision,
-                           preferred_element_type=jnp.float32)
-            o = o.astype(act).swapaxes(1, 2).reshape(k * b, s, hq * hd)
-            h = h + _mm(o, w["blocks/attn/wo"], "bsf,fd->bsd", dtype,
-                        precision).astype(act)
-            a = _rmsnorm(h, w["blocks/ln2/g"], eps_norm).astype(act)
-            gate = _mm(a, w["blocks/mlp/wg"], "bsd,df->bsf", dtype, precision)
-            up = _mm(a, w["blocks/mlp/wi"], "bsd,df->bsf", dtype, precision)
-            f = (jax.nn.silu(gate.astype(act).astype(jnp.float32))
-                 * up.astype(act).astype(jnp.float32)).astype(act)
-            h = h + _mm(f, w["blocks/mlp/wd"], "bsf,fd->bsd", dtype,
-                        precision).astype(act)
-            return h, None
-
-        x, _ = jax.lax.scan(layer, x, (layer_ids, stacked))
-        fn = perturbed(leaves[idx["final_norm/g"]],
-                       seeds[idx["final_norm/g"]], eps)
-        x = _rmsnorm(x, fn, eps_norm).astype(act)
-        head = perturbed(leaves[idx["lm_head/w"]], seeds[idx["lm_head/w"]],
-                         eps)
-        logits = _mm(x, head, "bsd,vd->bsv", dtype, precision)
-        tgt_logit = jnp.take_along_axis(
-            logits, targets.reshape(k * b, s)[..., None], axis=-1)[..., 0]
-        nll = jax.nn.logsumexp(logits, axis=-1) - tgt_logit
-        msk = mask.reshape(k * b, s)
-        row = jnp.sum(nll * msk, -1) / jnp.maximum(jnp.sum(msk, -1), 1.0)
-        return jnp.mean(row.reshape(k, b), axis=-1)
-
-    return loss
+    return cell.family(m["family"]).make_loss(m, leaf_paths, dtype,
+                                              precision)
 
 
 def path_names(tree) -> List[str]:

@@ -9,9 +9,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import counts  # noqa: E402
 import weights  # noqa: E402
 
-# one layer, d 4, 2 query heads and 1 KV head of dim 2, d_ff 8, vocab 10
-TINY = {"layers": 1, "d": 4, "heads": 2, "kv_heads": 1, "head_dim": 2,
-        "d_ff": 8, "vocab": 10}
+# one dense decoder layer, d 4, 2 query heads and 1 KV head of dim 2,
+# d_ff 8, vocab 10
+TINY = {"family": "dense_decoder", "layers": 1, "d": 4, "heads": 2,
+        "kv_heads": 1, "head_dim": 2, "d_ff": 8, "vocab": 10}
 ONE_SEQ = {"clients": 1, "batch": 1, "seq_len": 3, "n_perturb": 1}
 
 
@@ -54,6 +55,28 @@ def test_opt_125m_paper_job():
     # 77,217,792) FLOPs
     assert counts.round_flops(m, t) == 1_171_952_762_880
     assert counts.axpy_bytes(190_483_200, t) == 4_571_596_800
+
+
+@pytest.mark.parametrize("config,traffic,flops,attn_flops,attn_bytes", [
+    ("opt-125m", "sst2-k5", 1_171_952_762_880, 6_134_169_600, 754_974_720),
+    ("yi-6b.l8", "squad512", 22_961_230_708_736, 275_414_777_856,
+     2_415_919_104),
+    ("opt-125m", "long2k", 5_594_765_328_384, 618_777_280_512,
+     2_415_919_104),
+    ("opt-125m", "mesh4-k20", 4_687_811_051_520, 24_536_678_400,
+     3_019_898_880),
+])
+def test_cell_counts_pinned(config, traffic, flops, attn_flops, attn_bytes):
+    """The counts that mfu and flash_attention_roofline read in each cell,
+    as the dense decoder's counts gave them before it was its own file."""
+    import json
+    bench = Path(__file__).resolve().parents[1]
+    m = weights.dims(json.loads((bench / f"configs/{config}.json")
+                                .read_text()))
+    t = json.loads((bench / f"traffic/{traffic}.json").read_text())
+    assert counts.round_flops(m, t) == flops
+    assert counts.attention_flops(m, t) == attn_flops
+    assert counts.attention_bytes(m, t) == attn_bytes
 
 
 def test_peaks_table():

@@ -21,13 +21,14 @@ TINY_MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
 TINY_LIMITS = {"loss": 1e-5, "dtheta": 1e-2, "decode": 1e-3}
 
 
-@pytest.fixture(scope="module")
-def tiny_root(tmp_path_factory):
+def write_tiny(root, family="dense_decoder"):
+    """The tiny cells `tiny.one` and `tiny.mesh` (2 chips) under `root`: a
+    BENCHMARK.json, and a configuration of `family`, a traffic file and
+    limits under root/bench."""
     sys.path.insert(0, str(ROOT / "src"))
     from repro.models import registry
-    root = tmp_path_factory.mktemp("tiny")
     for sub in ("configs", "traffic", "limits"):
-        (root / "bench" / sub).mkdir(parents=True)
+        (root / "bench" / sub).mkdir(parents=True, exist_ok=True)
     cfg = json.loads((BENCH / "configs" / "opt-125m.json").read_text())
     cfg["as_run"].update(hidden_size=64, ffn_dim=128, num_hidden_layers=2,
                          num_attention_heads=4, num_key_value_heads=2,
@@ -35,6 +36,7 @@ def tiny_root(tmp_path_factory):
     cfg["model"] = TINY_MODEL
     cfg["params"] = dataclasses.replace(registry.get_arch("opt-125m"),
                                         **TINY_MODEL).param_count()
+    cfg["family"] = family
     (root / "bench/configs/tiny.json").write_text(json.dumps(cfg))
     traffic = json.loads((BENCH / "traffic" / "sst2-k5.json").read_text())
     traffic.update(clients=4, batch=2, seq_len=16, chunk_rounds=2,
@@ -64,12 +66,18 @@ def tiny_root(tmp_path_factory):
     return root
 
 
-def run_cell(root, workload, fault="none", trace=0, seed=2147483700):
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    return write_tiny(tmp_path_factory.mktemp("tiny"))
+
+
+def run_cell(root, workload, fault="none", trace=0, seed=2147483700,
+             driver=DRIVER):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH=str(ROOT / "src"))
     proc = subprocess.run(
-        [sys.executable, str(DRIVER), str(root), fault, "--",
+        [sys.executable, str(driver), str(root), fault, "--",
          "--workload", workload, "--seed", str(seed), "--seconds", "0.5",
          "--trace", str(trace)],
         capture_output=True, text=True, env=env, timeout=600)

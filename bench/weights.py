@@ -1,23 +1,22 @@
-"""Random weights for a dense decoder, made on the device from --seed.
+"""Random weights, made on the device from --seed.
 
 The benchmark makes the weights (not the program), so that the reference
 can be given the same weights without taking anything the program made.
 Each leaf is the counter-hash Gaussian stream of bench/reference.py under
 a seed of its own: a pure function of (seed, leaf, element index), cheap on
 the chip and the same under any sharding.
-The layout is the dense decoder's parameter tree: scan-stacked blocks with
-a leading layer dim, an embedding, a final norm and an untied LM head.
-Scales follow the usual decoder init: norms at 1, embedding and head at
-N(0, 0.02^2), projections at N(0, 1/fan_in).
+The sizes and the layout (the program's parameter tree, with each leaf's
+init scale) are the model family's (bench/families/<family>.py); the
+stream, the init of a leaf and the making of the whole tree are shared.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+import cell
 import reference
 
 # keeps the weight stream apart from every other use of the seed
@@ -25,36 +24,18 @@ _WEIGHTS_TAG = 0x3E1647
 
 
 def dims(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The sizes the benchmark reads from a configuration file."""
-    a = config["as_run"]
-    d = a["hidden_size"]
-    heads = a["num_attention_heads"]
-    return {
-        "layers": a["num_hidden_layers"], "d": d, "heads": heads,
-        "kv_heads": a.get("num_key_value_heads", heads),
-        "head_dim": a.get("head_dim", d // heads),
-        "d_ff": a.get("intermediate_size", a.get("ffn_dim")),
-        "vocab": a["vocab_size"], "rope_theta": float(a["rope_theta"]),
-        "norm_eps": float(a["rms_norm_eps"]),
-    }
+    """The sizes the benchmark reads from a configuration file, as its
+    family reads them, with the family's name under "family"."""
+    name = config["family"]
+    m = cell.family(name).dims(config)
+    m["family"] = name
+    return m
 
 
 def layout(m: Dict[str, Any]) -> Dict[str, Any]:
-    """{path: (shape, scale)} of every leaf; scale 0 marks a norm gain."""
-    L, d, hd = m["layers"], m["d"], m["head_dim"]
-    q, kv, ff, v = m["heads"] * hd, m["kv_heads"] * hd, m["d_ff"], m["vocab"]
-    lin = lambda d_in, d_out: ((L, d_in, d_out), 1.0 / math.sqrt(d_in))
-    return {
-        "blocks": {
-            "attn": {"wq": lin(d, q), "wk": lin(d, kv), "wv": lin(d, kv),
-                     "wo": lin(q, d)},
-            "ln1": {"g": ((L, d), 0.0)}, "ln2": {"g": ((L, d), 0.0)},
-            "mlp": {"wi": lin(d, ff), "wg": lin(d, ff), "wd": lin(ff, d)},
-        },
-        "embed": {"w": ((v, d), 0.02)},
-        "final_norm": {"g": ((d,), 0.0)},
-        "lm_head": {"w": ((v, d), 0.02)},
-    }
+    """{path: (shape, scale)} of every leaf, in the family's tree; scale 0
+    marks a norm gain."""
+    return cell.family(m["family"]).layout(m)
 
 
 def _is_spec(x) -> bool:
