@@ -339,17 +339,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         return kref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale)
     if impl in ("pallas", "pallas_interpret"):
-        d = q.shape[-1]
-        pad = (-d) % 128
-        sc = scale
-        if pad:
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad)))
-            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
-            v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                     scale=sc,
-                                     interpret=(impl == "pallas_interpret"))
-        return out[..., :d] if pad else out
+        return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                      scale=scale,
+                                      interpret=(impl == "pallas_interpret"))
     raise ValueError(f"unknown impl: {impl}")
 
 

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref, seeded_axpy
 
 jax.config.update("jax_enable_x64", False)
@@ -124,6 +125,13 @@ ATTN_CASES = [
     (2, 4, 2, 64, 64, 32, True, 16),         # local window
     (1, 4, 2, 1, 64, 32, True, None),        # decode: q = last position
     (2, 4, 4, 48, 96, 32, True, None),       # chunked prefill (sq < skv)
+    (2, 4, 4, 64, 64, 64, True, None),       # d = 64, no head-dim pad
+    (1, 2, 1, 1024, 1024, 64, True, None),   # multi-tile causal walk
+    (2, 8, 2, 64, 64, 64, True, None),       # q group folded per kv head
+    (8, 4, 2, 32, 32, 64, True, None),       # several kv rows in one step
+    (1, 2, 2, 700, 700, 64, True, None),     # q and kv tails off the tile
+    (1, 2, 2, 1100, 1100, 32, True, 300),    # window spanning tiles
+    (1, 2, 2, 600, 1300, 64, True, None),    # multi-tile sq < skv
 ]
 
 
@@ -164,6 +172,124 @@ def test_flash_attention_bf16():
     o_pl = ops.attention(q, k, v, causal=True, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(o_pl, np.float32),
                                np.asarray(o_ref, np.float32), atol=3e-2)
+
+
+# (b, hq, hkv, sq, skv, d, causal, window): shapes whose walks are checked
+PLAN_CASES = [
+    (4, 12, 12, 2048, 2048, 64, True, None),    # long2k
+    (1, 2, 2, 1100, 1100, 32, True, 300),       # window across tiles
+    (1, 2, 2, 600, 1300, 64, True, None),       # sq < skv
+    (1, 2, 2, 700, 700, 64, True, None),        # q and kv tails
+    (1, 4, 2, 1, 64, 32, True, None),           # decode
+    (2, 4, 4, 1000, 1000, 64, False, None),     # bidirectional
+]
+
+# the benchmark cells' attention calls: (b, hq, hkv, s, d)
+CELL_SHAPES = {"sst2-k5": (40, 12, 12, 64, 64),
+               "long2k": (4, 12, 12, 2048, 64),
+               "yi-squad512": (8, 32, 4, 512, 128)}
+
+
+def _cell_plan(name):
+    b, hq, hkv, s, d = CELL_SHAPES[name]
+    return fa.attention_tile_plan(b, hq, hkv, s, s, d)
+
+
+@pytest.mark.parametrize("case", PLAN_CASES)
+def test_attention_tile_plan_walks_live_pairs_only(case):
+    """Every walked pair has a visible (q, k) element and every visible
+    element lies in a walked pair; a pair is flagged masked exactly when
+    part of its tile is invisible or past the kv end; each q tile's walk
+    is contiguous, flagged first and last at its ends."""
+    b, hq, hkv, sq, skv, d, causal, window = case
+    plan = fa.attention_tile_plan(b, hq, hkv, sq, skv, d, d, causal, window)
+    tq, tk = plan.blk_q, plan.blk_k
+    q_pos = np.arange(sq)[:, None] + skv - sq
+    k_pos = np.arange(skv)[None, :]
+    vis = np.ones((sq, skv), bool)
+    if causal:
+        vis &= k_pos <= q_pos
+    if window is not None:
+        vis &= k_pos > q_pos - window
+    walked = {(i, j): f for i, j, f in plan.pairs}
+    assert len(walked) == len(plan.pairs)
+    n_q, n_k = -(-sq // tq), -(-skv // tk)
+    for i in range(n_q):
+        for j in range(n_k):
+            tile = vis[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+            if (i, j) not in walked:
+                assert not tile.any(), (i, j)
+                continue
+            masked = not tile.all() or (j + 1) * tk > skv
+            assert bool(walked[(i, j)] & fa.MASKED) == masked, (i, j)
+    qis = [i for i, _, _ in plan.pairs]
+    assert qis == sorted(qis) and set(qis) == set(range(n_q))
+    for i in range(n_q):
+        flags = [f for qi, _, f in plan.pairs if qi == i]
+        assert [bool(f & fa.FIRST) for f in flags] == \
+            [True] + [False] * (len(flags) - 1)
+        assert [bool(f & fa.LAST) for f in flags] == \
+            [False] * (len(flags) - 1) + [True]
+    blocks = (b * hkv // plan.rows) * (hq // hkv // plan.heads)
+    assert plan.steps == blocks * len(plan.pairs)
+    assert plan.dead == blocks * (n_q * n_k - len(plan.pairs))
+
+
+def test_attention_tile_plan_long_causal_counts():
+    """Causal S = 2048 at 512 tiles: 10 of the 16 pairs are live."""
+    plan = _cell_plan("long2k")
+    assert (plan.blk_q, plan.blk_k) == (512, 512)
+    assert len(plan.pairs) == 10
+    assert plan.steps == 48 * 10 and plan.dead == 48 * 6
+
+
+def _kv_fetches(plan, b, hq, hkv):
+    """DMAs of k/v blocks: the walk fetches one when the block index
+    (kv row, kv tile) changes from the step before."""
+    idx = [(r, ki) for r in range(b * hkv // plan.rows)
+           for _ in range(hq // hkv // plan.heads) for _, ki, _ in plan.pairs]
+    return 1 + sum(x != y for x, y in zip(idx, idx[1:]))
+
+
+@pytest.mark.parametrize("shape", [(2, 8, 2, 64, 64), (8, 32, 4, 512, 128),
+                                   (8, 4, 2, 32, 64)],
+                         ids=["gqa", "yi", "folded_rows"])
+def test_attention_tile_plan_folds_gqa_group(shape):
+    """The q heads of one kv head share a step, so k/v are fetched once per
+    kv head, not once per q head."""
+    b, hq, hkv, s, d = shape
+    plan = fa.attention_tile_plan(b, hq, hkv, s, s, d)
+    assert plan.heads == hq // hkv
+    assert plan.blk_k == s and _kv_fetches(plan, b, hq, hkv) == \
+        b * hkv // plan.rows
+
+
+@pytest.mark.parametrize("name", sorted(CELL_SHAPES))
+def test_attention_tile_plan_fits_vmem(name):
+    plan = _cell_plan(name)
+    assert plan.vmem_bytes <= fa.VMEM_BUDGET
+    assert plan.vmem_bytes + fa.VMEM_SLACK <= plan.vmem_limit
+
+
+# name: (blk_q, blk_k, rows, heads, steps per call, dead pairs per call);
+# the fixed 128×128 walk had 480 / 12,288 (4,608 dead) / 4,096 (1,536)
+CELL_PLANS = {"sst2-k5": (64, 64, 32, 1, 15, 0),
+              "long2k": (512, 512, 1, 1, 480, 288),
+              "yi-squad512": (256, 512, 1, 8, 64, 0)}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_PLANS))
+def test_attention_tile_plan_cells_pinned(name):
+    p = _cell_plan(name)
+    assert (p.blk_q, p.blk_k, p.rows, p.heads, p.steps, p.dead) == \
+        CELL_PLANS[name]
+
+
+def test_attention_tile_plan_decode_no_more_steps():
+    """One q row: q takes 8 rows, kv its own 64 (no pad to 128), and the
+    group shares a step: 2 steps where the fixed walk took 4."""
+    p = fa.attention_tile_plan(1, 4, 2, 1, 64, 32)
+    assert (p.blk_q, p.blk_k, p.heads, p.steps) == (8, 64, 2, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -16,8 +16,10 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.perturbed_matmul import perturbed_matmul_pallas
 from repro.kernels.seeded_axpy import seeded_axpy_pallas
@@ -132,3 +134,32 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True),
         qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+def _entry_opcodes(text: str) -> set:
+    entry = text[text.index("\nENTRY"):]
+    return set(re.findall(r"%\S+ = .*? ([a-z][a-z-]*)\(", entry))
+
+
+@pytest.mark.parametrize("q_shape,kv_shape",
+                         [((40, 12, 64, 64), (40, 12, 64, 64)),
+                          ((4, 12, 2048, 64), (4, 12, 2048, 64)),
+                          ((8, 32, 512, 128), (8, 4, 512, 128))],
+                         ids=["opt_sst2_k5", "opt_long2k", "yi_squad512"])
+def test_attention_cells_compile_without_pad_for_v5e(one_chip, q_shape,
+                                                     kv_shape):
+    """`ops.attention` at the benchmark cells' shapes is the kernel alone:
+    the head dim of 64 enters unpadded, so no pad or slice surrounds the
+    custom call (inputs and output pinned to the default layout, as the
+    kernel takes them)."""
+    row_major = lambda n: Format(Layout(tuple(range(n))), one_chip)
+    spec = lambda s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                          sharding=row_major(len(s)))
+    text = jax.jit(
+        lambda q, k, v: ops.attention(q, k, v, causal=True, impl="pallas"),
+        out_shardings=row_major(4)).lower(
+        spec(q_shape), spec(kv_shape), spec(kv_shape)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "flash_attention_pallas" in text
+    assert not _entry_opcodes(text) & {"pad", "slice", "slice-start",
+                                       "copy", "copy-start", "fusion"}
